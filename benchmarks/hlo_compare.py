@@ -252,8 +252,11 @@ print("RESULT " + json.dumps(out))
 
 
 def _run_script(script):
+    """Run ``script`` in a child pinned to the CPU: it counts HLO on fake CPU
+    devices, and on a TPU host it must not contend for the parent's chip."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=env, timeout=900)

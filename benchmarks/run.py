@@ -22,6 +22,9 @@ Prints ``name,us_per_call,derived`` CSV rows (harness contract).
                   multi-writer save-time sweep over writers in {1, 2, 4}
                   (``ckpt_multiwriter_*`` rows, ISSUE 6)
 
+A module that raises becomes an ``ERROR:`` row and the harness exits 1
+after printing every row.
+
 Besides the CSV, the harness persists ``BENCH_overlap.json`` next to the repo
 root: per-mode step times from ``benchmarks/overlap.py``, the micro matmul
 rows, the overlap-aware comm-model theory (bf16 and int8 wire), the
@@ -40,6 +43,7 @@ given file, persisting ``calibrated_overlap_eff`` + the recomputed
 import argparse
 import json
 import os
+import sys
 
 BENCH_JSON = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "BENCH_overlap.json")
@@ -131,6 +135,8 @@ def main() -> None:
     print("name,us_per_call,derived")
     for r in rows:
         print(r)
+    if any(",ERROR:" in r for r in rows):
+        sys.exit(1)
 
 
 if __name__ == '__main__':

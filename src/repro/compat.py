@@ -1,10 +1,9 @@
-"""Small jax version/backend-compatibility shims.
+"""Small backend shims shared by the model and kernel code.
 
-The repo targets the ``jax.shard_map`` API (jax >= 0.6, ``check_vma=``) but must
-also run on the 0.4.x series the container ships, where shard_map lives in
-``jax.experimental.shard_map`` and the flag is spelled ``check_rep=``.  Same
-story for ``Compiled.cost_analysis()``, which returns a list of per-program
-dicts on old jaxlibs and a plain dict on new ones.
+``shard_map`` wraps ``jax.shard_map`` with replication checking off by
+default, ``cost_analysis_dict`` flattens ``Compiled.cost_analysis()``, and
+``enable_compile_cache`` gives every entry point the same persistent
+compilation cache.
 
 This module also hosts the *remote-DMA emulation shim* for the fused Pallas
 ring kernels (kernels/ring_matmul.py): only a real TPU backend can execute
@@ -15,25 +14,34 @@ compute still running through the Pallas tile loop in interpret mode."""
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
 from jax import lax
 
-try:                                    # jax >= 0.6: public API, check_vma flag
-    _new_shard_map = jax.shard_map
-except AttributeError:
-    _new_shard_map = None
+# The persistent compilation cache's fallback home: fixed and inside the
+# checkout (listed in .gitignore), because the path is part of the cache key.
+CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
-if _new_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it and
+    nothing else is set here; otherwise the cache goes to :data:`CACHE_DIR`.
+    Call before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
 
 
 def shard_map(f, mesh, in_specs, out_specs, check=False):
     """Uniform shard_map with replication checking disabled by default."""
-    if _new_shard_map is not None:
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check)
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def remote_dma_supported() -> bool:
@@ -42,11 +50,9 @@ def remote_dma_supported() -> bool:
     True only on an actual TPU backend — the Pallas interpreter and the CPU/GPU
     backends have no inter-chip DMA engine.  The fused ring kernels use this to
     pick between the single-kernel remote-DMA path and the ppermute-emulated
-    path (``ring_step_permute``)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:          # no backend initialized / headless analysis
-        return False
+    path (``ring_step_permute``).  A backend that fails to initialize raises
+    here rather than silently selecting the emulation."""
+    return jax.default_backend() == "tpu"
 
 
 def ring_step_permute(x, axis_name: str, n: int, shift: int = 1):
@@ -61,10 +67,5 @@ def ring_step_permute(x, axis_name: str, n: int, shift: int = 1):
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to a single flat dict."""
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a flat dict (empty when unavailable)."""
+    return dict(compiled.cost_analysis() or {})

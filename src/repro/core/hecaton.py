@@ -493,9 +493,6 @@ def fused_lm_loss(x: jax.Array, w: jax.Array, labels: jax.Array,
                       == jnp.arange(v_loc)[None, None, :])
             gold = lax.psum(jnp.sum(lg * onehot, axis=-1), h_ax)
             wm = mc.astype(jnp.float32)
-            # rank-1 carry: scalar carries become scalar residuals under
-            # jax.checkpoint, which old shard_map's partial-eval mis-names
-            # (jax<=0.4.x _SpecError); a [2]-vector sidesteps the bug.
             return carry + jnp.stack([jnp.sum((lse - gold) * wm),
                                       jnp.sum(wm)]), None
 

@@ -338,6 +338,18 @@ def ring_matmul_rs(x, w, axis_name: str, *, scatter_dim: int, n: int,
 # ---------------------------------------------------------------------------
 
 
+# (op, x shape, w shape) of every collective that was asked to run fused but
+# failed its fused_ok_* gate and took the ppermute ring instead, appended at
+# trace time.  A caller that must see such a degrade clears it before tracing.
+fused_fallbacks: list = []
+
+
+def _fused(op: str, ok: bool, x, w, n: int) -> bool:
+    if not ok and n > 1:
+        fused_fallbacks.append((op, tuple(x.shape), tuple(w.shape)))
+    return ok
+
+
 def ag_matmul(x, w, axis_name: str, *, dim: int, n: int, overlap: str,
               mesh_axes=None, comm_dtype: str = "bf16"):
     """AG ⊕ matmul (gathered dim is a batch dim) under the given mode.
@@ -345,8 +357,9 @@ def ag_matmul(x, w, axis_name: str, *, dim: int, n: int, overlap: str,
     ``mesh_axes`` (the enclosing mesh's full axis-name tuple) lets the TPU
     single-kernel path address ring neighbours by mesh coordinates; without
     it the fused mode still runs, via its ppermute-emulated path."""
-    if overlap == "fused" and RM.fused_ok_ag(x.shape, w.shape, n, dim,
-                                             x.dtype.itemsize):
+    if overlap == "fused" and _fused(
+            "ag_matmul", RM.fused_ok_ag(x.shape, w.shape, n, dim,
+                                        x.dtype.itemsize), x, w, n):
         return RM.ag_matmul(x, w, axis_name, dim=dim, n=n,
                             mesh_axes=mesh_axes, comm_dtype=comm_dtype)
     return ring_ag_matmul(x, w, axis_name, dim=dim, n=n,
@@ -356,8 +369,9 @@ def ag_matmul(x, w, axis_name: str, *, dim: int, n: int, overlap: str,
 def matmul_rs(x, w, axis_name: str, *, scatter_dim: int, n: int,
               overlap: str, mesh_axes=None, comm_dtype: str = "bf16"):
     """matmul ⊕ RS under the given mode."""
-    if overlap == "fused" and RM.fused_ok_rs(x.shape, w.shape, n,
-                                             scatter_dim, x.dtype.itemsize):
+    if overlap == "fused" and _fused(
+            "matmul_rs", RM.fused_ok_rs(x.shape, w.shape, n, scatter_dim,
+                                        x.dtype.itemsize), x, w, n):
         return RM.matmul_rs(x, w, axis_name, scatter_dim=scatter_dim, n=n,
                             mesh_axes=mesh_axes, comm_dtype=comm_dtype)
     return ring_matmul_rs(x, w, axis_name, scatter_dim=scatter_dim, n=n,
@@ -368,8 +382,10 @@ def ag_matmul_contract(x, w, axis_name: str, *, n: int, overlap: str,
                        out_dtype=None, mesh_axes=None,
                        comm_dtype: str = "bf16"):
     """AG ⊕ matmul over the contracted dim under the given mode."""
-    if overlap == "fused" and RM.fused_ok_contract(x.shape, w.shape, n,
-                                                   x.dtype.itemsize):
+    if overlap == "fused" and _fused(
+            "ag_matmul_contract", RM.fused_ok_contract(x.shape, w.shape, n,
+                                                       x.dtype.itemsize),
+            x, w, n):
         return RM.ag_matmul_contract(x, w, axis_name, n=n,
                                      out_dtype=out_dtype,
                                      mesh_axes=mesh_axes,
@@ -386,11 +402,10 @@ def matmul_rs_pair(x, w1, w1b, axis_name: str, *, scatter_dim: int, n: int,
 
     Fused mode reads each x tile once for both products inside one kernel;
     the ring/bidir path runs two matmul-RS rings over the shared gather."""
-    if (overlap == "fused" and scatter_dim != x.ndim - 1
-            and RM.fused_ok_rs(x.shape, w1.shape, n, scatter_dim,
-                               x.dtype.itemsize)
-            and RM.fused_ok_rs(x.shape, w1b.shape, n, scatter_dim,
-                               x.dtype.itemsize)):
+    if overlap == "fused" and _fused(
+            "matmul_rs_pair", RM.fused_ok_pair(x.shape, w1.shape, w1b.shape,
+                                               n, scatter_dim,
+                                               x.dtype.itemsize), x, w1, n):
         return RM.matmul_rs_pair(x, w1, w1b, axis_name,
                                  scatter_dim=scatter_dim, n=n,
                                  mesh_axes=mesh_axes, comm_dtype=comm_dtype)

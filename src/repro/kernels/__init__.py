@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels.  ring_matmul.py (the fused NoP ring collectives) is on the
+# model path; matmul.py, flash_attention.py and ssd.py are standalone kernels
+# checked against ref.py by tests/test_kernels.py.

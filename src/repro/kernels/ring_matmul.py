@@ -86,6 +86,9 @@ from repro.kernels.matmul import _epilogue, _mm_bias_kernel, _mm_kernel
 
 # MXU-aligned tile preferences (same defaults as kernels/matmul.py).
 BLOCK_M, BLOCK_N, BLOCK_K = 128, 128, 512
+# TPU vreg tiling: a block's second-to-last dim must be a multiple of SUBLANE
+# and its last dim a multiple of LANE (or span the whole array dimension).
+SUBLANE, LANE = 8, 128
 
 # Per-core VMEM budget for the single-kernel scratch (double-buffered shard /
 # accumulator pair + fp32 acc tiles); shapes whose scratch would exceed it are
@@ -98,27 +101,34 @@ VMEM_BUDGET = 12 * 2 ** 20
 # ---------------------------------------------------------------------------
 
 
-def pick_block(dim: int, pref: int) -> int:
-    """Largest tile <= ``pref`` that divides ``dim`` (always succeeds).
+def pick_block(dim: int, pref: int, align: int = SUBLANE) -> int:
+    """Largest multiple of ``align`` <= ``pref`` dividing ``dim``, else ``dim``.
 
     A dim no larger than the preference is its own (single) tile; otherwise
-    prefer the MXU-aligned size and degrade to the largest divisor.  The
-    degraded tiles keep the emulated path (and transposed backward shapes)
-    correct on any extent; :func:`aligned` is the stricter gate the overlap
-    dispatcher uses to decide fused vs ring."""
+    prefer the MXU-aligned size and degrade to the largest aligned divisor,
+    or to one full-extent tile when no aligned divisor exists.  Every block
+    this returns is one the TPU compiler accepts (a multiple of the (8, 128)
+    tiling or the whole dimension), so any shape that passes a
+    :func:`fused_ok_ag`-style gate also compiles its transposed backward
+    matmuls.  Pass ``align=LANE`` for a block's last (lane) dimension."""
     if dim <= pref:
         return max(dim, 1)
-    if dim % pref == 0:
-        return pref
-    for b in range(pref - 1, 0, -1):
+    for b in range(pref - pref % align, 0, -align):
         if dim % b == 0:
             return b
-    return 1
+    return dim
 
 
 def aligned(dim: int, pref: int) -> bool:
     """Tile-aligned in the fused-kernel sense: one tile, or MXU-tiled."""
     return dim <= pref or dim % pref == 0
+
+
+def _blocks(m: int, n: int, k: int) -> Tuple[int, int, int]:
+    """(bm, bn, bk) tiles of an [m,k] @ [k,n] matmul the TPU compiler accepts:
+    bm indexes sublanes, bn and bk index lanes of some block."""
+    return (pick_block(m, BLOCK_M), pick_block(n, BLOCK_N, LANE),
+            pick_block(k, BLOCK_K, LANE))
 
 
 def _mk(shape3) -> Tuple[int, int]:
@@ -145,25 +155,21 @@ def _tile_bytes(itemsize: int) -> int:
                    + BLOCK_M * BLOCK_N) * itemsize)
 
 
-def fused_ok_ag(x_shape, w_shape, n: int, dim: int = 1,
-                itemsize: int = 4) -> bool:
-    """Can ``ag_matmul`` run fused for x [b,t,h] (gather ``dim``), w [h,o]?
-
-    Requires MXU-tile-aligned dims AND the double-buffered shard pair fitting
-    the VMEM budget — anything else degrades to the ppermute ring."""
-    if n <= 1 or len(x_shape) != 3 or dim != 1:
+def _ag_fits(x_shape, w_shape, n: int, itemsize: int) -> bool:
+    """The ``_ag_matmul_tpu`` kernel alone: aligned dims, shard pair in VMEM."""
+    if n <= 1 or len(x_shape) != 3 or x_shape[-1] != w_shape[0]:
         return False
     m, k = _mk(x_shape)
-    return (x_shape[-1] == w_shape[0] and aligned(m, BLOCK_M)
-            and aligned(k, BLOCK_K) and aligned(w_shape[-1], BLOCK_N)
+    return (aligned(m, BLOCK_M) and aligned(k, BLOCK_K)
+            and aligned(w_shape[-1], BLOCK_N)
             and _fits_vmem(2 * _prod(x_shape) * itemsize,
                            _tile_bytes(itemsize)))
 
 
-def fused_ok_rs(x_shape, w_shape, n: int, scatter_dim: int,
-                itemsize: int = 4) -> bool:
-    """Can ``matmul_rs`` run fused for x [b,t,h] @ w [h,o], scatter ``dim``?"""
-    if n <= 1 or len(x_shape) != 3:
+def _rs_fits(x_shape, w_shape, n: int, scatter_dim: int,
+             itemsize: int) -> bool:
+    """The ``_matmul_rs_tpu`` kernel alone: accumulator pair in VMEM."""
+    if n <= 1 or len(x_shape) != 3 or x_shape[-1] != w_shape[0]:
         return False
     last = scatter_dim == len(x_shape) - 1
     scattered = w_shape[-1] if last else x_shape[scatter_dim]
@@ -176,16 +182,13 @@ def fused_ok_rs(x_shape, w_shape, n: int, scatter_dim: int,
     else:
         m, k, nn = x_shape[0] * chunk, x_shape[-1], w_shape[-1]
         out_elts = x_shape[0] * chunk * w_shape[-1]
-    return (x_shape[-1] == w_shape[0] and aligned(m, BLOCK_M)
-            and aligned(k, BLOCK_K) and aligned(nn, BLOCK_N)
+    return (aligned(m, BLOCK_M) and aligned(k, BLOCK_K) and aligned(nn, BLOCK_N)
             and _fits_vmem(2 * out_elts * itemsize, _tile_bytes(itemsize)))
 
 
-def fused_ok_contract(x_shape, w_shape, n: int, itemsize: int = 4) -> bool:
-    """Can ``ag_matmul_contract`` run fused (gathered dim contracted)?
-
-    The fp32 accumulator spanning ring steps lives in VMEM whole, so it
-    counts against the budget alongside the circulating shard pair."""
+def _contract_fits(x_shape, w_shape, n: int, itemsize: int) -> bool:
+    """The ``_ag_matmul_contract_tpu`` kernel alone: the fp32 accumulator
+    spanning ring steps lives in VMEM whole, beside the shard pair."""
     if n <= 1 or len(x_shape) != 3 or w_shape[0] != n * x_shape[-1]:
         return False
     m, k = _mk(x_shape)
@@ -193,6 +196,59 @@ def fused_ok_contract(x_shape, w_shape, n: int, itemsize: int = 4) -> bool:
             and aligned(w_shape[-1], BLOCK_N)
             and _fits_vmem(2 * _prod(x_shape) * itemsize,
                            m * w_shape[-1] * 4, _tile_bytes(itemsize)))
+
+
+# The fused_ok_* gates admit a shape only if the forward kernel AND the fused
+# kernel its custom-VJP backward runs (the transposed ring) both fit: a step
+# that passes the gate compiles its gradient too.
+
+
+def fused_ok_ag(x_shape, w_shape, n: int, dim: int = 1,
+                itemsize: int = 4) -> bool:
+    """Can ``ag_matmul`` run fused for x [b,t,h] (gather ``dim``), w [h,o]?
+    Backward: a token-dim matmul-RS of dy [b,n·t,o] @ wᵀ."""
+    if dim != 1 or not _ag_fits(x_shape, w_shape, n, itemsize):
+        return False
+    b, t, h = x_shape
+    o = w_shape[-1]
+    return _rs_fits((b, n * t, o), (o, h), n, 1, itemsize)
+
+
+def fused_ok_rs(x_shape, w_shape, n: int, scatter_dim: int,
+                itemsize: int = 4) -> bool:
+    """Can ``matmul_rs`` run fused for x [b,t,h] @ w [h,o], scatter ``dim``?
+    Backward: a contracted-dim AG-matmul (hidden scatter) or a token-dim
+    AG-matmul (token scatter) of the output cotangent with wᵀ."""
+    if not _rs_fits(x_shape, w_shape, n, scatter_dim, itemsize):
+        return False
+    b, t, h = x_shape
+    o = w_shape[-1]
+    if scatter_dim == len(x_shape) - 1:
+        return _contract_fits((b, t, o // n), (o, h), n, itemsize)
+    return _ag_fits((b, t // n, o), (o, h), n, itemsize)
+
+
+def fused_ok_contract(x_shape, w_shape, n: int, itemsize: int = 4) -> bool:
+    """Can ``ag_matmul_contract`` run fused (gathered dim contracted)?
+    Backward: a hidden-dim matmul-RS of dy [b,t,o] @ wᵀ."""
+    if not _contract_fits(x_shape, w_shape, n, itemsize):
+        return False
+    b, t, _ = x_shape
+    o = w_shape[-1]
+    return _rs_fits((b, t, o), (o, w_shape[0]), n, 2, itemsize)
+
+
+def fused_ok_pair(x_shape, w1_shape, w1b_shape, n: int, scatter_dim: int,
+                  itemsize: int = 4) -> bool:
+    """Can ``matmul_rs_pair`` run fused?  Its forward is one matmul-RS over
+    the column-concatenated weights; its backward is one token-dim AG-matmul
+    per weight."""
+    if scatter_dim != len(x_shape) - 2:
+        return False
+    cat = (w1_shape[0], w1_shape[-1] + w1b_shape[-1])
+    return (_rs_fits(x_shape, cat, n, scatter_dim, itemsize)
+            and fused_ok_rs(x_shape, w1_shape, n, scatter_dim, itemsize)
+            and fused_ok_rs(x_shape, w1b_shape, n, scatter_dim, itemsize))
 
 
 # ---------------------------------------------------------------------------
@@ -252,12 +308,13 @@ def _tile_mm_raw(x, w, bias=None, *, act: str = "none", out_dtype=None,
     M, K = x.shape
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
+    out_dtype = out_dtype or x.dtype
     if interpret:
         bm, bn, bk = M, N, K
+        # XLA:CPU has no bf16 x bf16 -> f32 dot; the f32 upcast is exact
+        x, w = x.astype(jnp.float32), w.astype(jnp.float32)
     else:
-        bm, bn, bk = pick_block(M, BLOCK_M), pick_block(N, BLOCK_N), \
-            pick_block(K, BLOCK_K)
-    out_dtype = out_dtype or x.dtype
+        bm, bn, bk = _blocks(M, N, K)
     call = _tile_mm_call(M, K, N, bm, bn, bk, bias is not None, act,
                          jnp.dtype(out_dtype).name, interpret)
     if bias is None:
@@ -738,6 +795,30 @@ def _ring_ids(axis_name: str, n: int, mesh_axes):
     return jnp.stack([me] + right + left).astype(jnp.int32), len(axes)
 
 
+def _quant_lane_scales(x):
+    """``quant.quant_int8`` with the per-row scales stored lane-major,
+    ``x.shape[:-1]`` instead of ``x.shape[:-1] + (1,)``: the TPU's DMA
+    cannot slice a buffer whose last dim is a single (padded) lane."""
+    q, scale = Q.quant_int8(x)
+    return q, jnp.max(scale, axis=-1)
+
+
+def _row_scales(sbuf, slot, bi, start, size: int):
+    """Scales of rows ``[start, start+size)`` of batch ``bi`` in ``slot`` of
+    a lane-major scale buffer, as a ``[size, 1]`` column for the dequant."""
+    return sbuf[slot, pl.ds(bi, 1), pl.ds(start, size)].reshape(size, 1)
+
+
+def _final_step_block(s, n: int, *idx):
+    """Output block index for kernels that emit only on the last ring step.
+
+    Earlier steps park on block 0, so every output block is visited in one
+    contiguous run (block 0's run ends at the last step's first tile) and
+    nothing is written back to HBM before it holds its final value."""
+    last = (s == n - 1).astype(jnp.int32)
+    return tuple(v * last for v in idx)
+
+
 def _nbr(ids_ref, n_axes: int, which: str):
     off = 1 if which == "right" else 1 + n_axes
     return tuple(ids_ref[off + i] for i in range(n_axes))
@@ -759,8 +840,7 @@ def _ag_matmul_tpu(x, w, *, axis_name: str, dim: int, n: int,
     assert dim == 1, "token-dim gather only"
     b, t, h = x.shape
     o = w.shape[-1]
-    bm, bn, bk = pick_block(t, BLOCK_M), pick_block(o, BLOCK_N), \
-        pick_block(h, BLOCK_K)
+    bm, bn, bk = _blocks(t, o, h)
     mt, nt, kt = t // bm, o // bn, h // bk
     ids, n_axes = _ring_ids(axis_name, n, mesh_axes)
     quant = comm_dtype == "int8" and Q.quant_ok(x.shape, x.dtype)
@@ -832,7 +912,7 @@ def _ag_matmul_tpu(x, w, *, axis_name: str, dim: int, n: int,
         if quant:
             xt = (buf[slot, bi, pl.ds(i * bm, bm),
                       pl.ds(k * bk, bk)].astype(jnp.float32)
-                  * sbuf[slot, bi, pl.ds(i * bm, bm), :]).astype(w_ref.dtype)
+                  * _row_scales(sbuf, slot, bi, i * bm, bm)).astype(w_ref.dtype)
         else:
             xt = buf[slot, bi, pl.ds(i * bm, bm), pl.ds(k * bk, bk)]
         acc[...] += jnp.dot(xt, w_ref[...],
@@ -861,15 +941,15 @@ def _ag_matmul_tpu(x, w, *, axis_name: str, dim: int, n: int,
 
     grid = (n, b, mt, nt, kt)
     if quant:
-        xq, xs = Q.quant_int8(x)
+        xq, xs = _quant_lane_scales(x)
         in_specs = [
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((bk, bn), lambda s, bi, i, j, k, ids: (k, j)),
         ]
         scratch = [
             pltpu.VMEM((2, b, t, h), jnp.int8),
-            pltpu.VMEM((2, b, t, 1), jnp.float32),
+            pltpu.VMEM((2, b, t), jnp.float32),
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA((2,)),
@@ -881,7 +961,7 @@ def _ag_matmul_tpu(x, w, *, axis_name: str, dim: int, n: int,
         operands = (ids, xq, xs, w)
     else:
         in_specs = [
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((bk, bn), lambda s, bi, i, j, k, ids: (k, j)),
         ]
         scratch = [
@@ -900,13 +980,13 @@ def _ag_matmul_tpu(x, w, *, axis_name: str, dim: int, n: int,
             grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (1, bm, bn),
+                (None, bm, bn),
                 lambda s, bi, i, j, k, ids:
                     (bi, ((ids[0] - s) % n) * mt + i, j)),
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((b, n * t, o), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             collective_id=collective_id, has_side_effects=True),
     )(*operands)
@@ -942,13 +1022,11 @@ def _matmul_rs_tpu(x, w, *, axis_name: str, scatter_dim: int, n: int,
     scattered = o if last else x.shape[scatter_dim]
     chunk = scattered // n
     if last:
-        bm, bn, bk = pick_block(t, BLOCK_M), pick_block(chunk, BLOCK_N), \
-            pick_block(h, BLOCK_K)
+        bm, bn, bk = _blocks(t, chunk, h)
         mt, nt, kt = t // bm, chunk // bn, h // bk
         out_shape = (b, t, chunk)
     else:
-        bm, bn, bk = pick_block(chunk, BLOCK_M), pick_block(o, BLOCK_N), \
-            pick_block(h, BLOCK_K)
+        bm, bn, bk = _blocks(chunk, o, h)
         mt, nt, kt = chunk // bm, o // bn, h // bk
         out_shape = (b, chunk, o)
     ids, n_axes = _ring_ids(axis_name, n, mesh_axes)
@@ -1043,7 +1121,7 @@ def _matmul_rs_tpu(x, w, *, axis_name: str, scatter_dim: int, n: int,
                 @pl.when(s > 0)
                 def _add():   # dequantize the received tile, fold this step's
                     got = (buf[(slot,) + idxs].astype(jnp.float32)
-                           * sbuf[slot, bi, pl.ds(i * bm, bm), :])
+                           * _row_scales(sbuf, slot, bi, i * bm, bm))
                     work[idxs] = got.astype(work.dtype) + tile
             else:
                 tile = acc[...].astype(buf.dtype)
@@ -1060,9 +1138,7 @@ def _matmul_rs_tpu(x, w, *, axis_name: str, scatter_dim: int, n: int,
         if quant:   # the outbound pair is rebuilt from work at every send
             @pl.when((s < n - 1) & lastt)
             def _requant():
-                qv, sv = Q.quant_int8(work[...])
-                buf[slot] = qv
-                sbuf[slot] = sv
+                buf[slot], sbuf[slot] = _quant_lane_scales(work[...])
 
         @pl.when((s < n - 1) & lastt)
         def _send():           # start only — completion checked next step
@@ -1097,7 +1173,7 @@ def _matmul_rs_tpu(x, w, *, axis_name: str, scatter_dim: int, n: int,
     if quant:
         scratch = [
             pltpu.VMEM((2,) + out_shape, jnp.int8),
-            pltpu.VMEM((2,) + out_shape[:-1] + (1,), jnp.float32),
+            pltpu.VMEM((2,) + out_shape[:-1], jnp.float32),
             pltpu.VMEM(out_shape, x.dtype),
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
@@ -1122,11 +1198,12 @@ def _matmul_rs_tpu(x, w, *, axis_name: str, scatter_dim: int, n: int,
             grid=grid,
             in_specs=[x_spec, w_spec],
             out_specs=pl.BlockSpec(
-                (1, bm, bn), lambda s, bi, i, j, k, ids: (bi, i, j)),
+                (None, bm, bn),
+                lambda s, bi, i, j, k, ids: _final_step_block(s, n, bi, i, j)),
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             collective_id=collective_id, has_side_effects=True),
     )(ids, x, w)
@@ -1147,9 +1224,9 @@ def _ag_matmul_contract_tpu(x, w, *, axis_name: str, n: int, out_dtype=None,
     o = w.shape[-1]
     m = b * t
     dt = out_dtype or x.dtype
-    bm, bn, bk = pick_block(m, BLOCK_M), pick_block(o, BLOCK_N), \
-        pick_block(h, BLOCK_K)
-    mt, nt, kt = m // bm, o // bn, h // bk
+    bm, bn, bk = _blocks(t, o, h)       # bm | t: a row tile never spans b
+    tt = t // bm
+    mt, nt, kt = b * tt, o // bn, h // bk
     ids, n_axes = _ring_ids(axis_name, n, mesh_axes)
     quant = comm_dtype == "int8" and Q.quant_ok(x.shape, x.dtype)
 
@@ -1214,15 +1291,13 @@ def _ag_matmul_contract_tpu(x, w, *, axis_name: str, n: int, out_dtype=None,
                     device_id_type=pltpu.DeviceIdType.MESH)
                 rdma_s.start()
 
+        bi, rows = i // tt, pl.ds((i % tt) * bm, bm)
         if quant:
-            xt = (buf[slot].reshape(m, h)[pl.ds(i * bm, bm),
-                                          pl.ds(k * bk, bk)]
-                  .astype(jnp.float32)
-                  * sbuf[slot].reshape(m, 1)[pl.ds(i * bm, bm), :]
+            xt = (buf[slot, bi, rows, pl.ds(k * bk, bk)].astype(jnp.float32)
+                  * _row_scales(sbuf, slot, bi, (i % tt) * bm, bm)
                   ).astype(w_ref.dtype)
         else:
-            xt = buf[slot].reshape(m, h)[pl.ds(i * bm, bm),
-                                         pl.ds(k * bk, bk)]
+            xt = buf[slot, bi, rows, pl.ds(k * bk, bk)]
         acc[pl.ds(i * bm, bm), pl.ds(j * bn, bn)] += jnp.dot(
             xt, w_ref[...], preferred_element_type=jnp.float32)
 
@@ -1248,10 +1323,10 @@ def _ag_matmul_contract_tpu(x, w, *, axis_name: str, n: int, out_dtype=None,
                 device_id_type=pltpu.DeviceIdType.MESH)
 
     if quant:
-        xq, xs = Q.quant_int8(x)
+        xq, xs = _quant_lane_scales(x)
         in_specs = [
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             # w row-block follows the circulating shard's source rank
             pl.BlockSpec((h // kt, o // nt),
                          lambda s, i, j, k, ids:
@@ -1259,7 +1334,7 @@ def _ag_matmul_contract_tpu(x, w, *, axis_name: str, n: int, out_dtype=None,
         ]
         scratch = [
             pltpu.VMEM((2, b, t, h), jnp.int8),
-            pltpu.VMEM((2, b, t, 1), jnp.float32),
+            pltpu.VMEM((2, b, t), jnp.float32),
             pltpu.VMEM((m, o), jnp.float32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA((2,)),
@@ -1271,7 +1346,7 @@ def _ag_matmul_contract_tpu(x, w, *, axis_name: str, n: int, out_dtype=None,
         operands = (ids, xq, xs, w)
     else:
         in_specs = [
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             # w row-block follows the circulating shard's source rank
             pl.BlockSpec((h // kt, o // nt),
                          lambda s, i, j, k, ids:
@@ -1294,11 +1369,11 @@ def _ag_matmul_contract_tpu(x, w, *, axis_name: str, n: int, out_dtype=None,
             grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (m // mt, o // nt), lambda s, i, j, k, ids: (i, j)),
+                (bm, bn), lambda s, i, j, k, ids: _final_step_block(s, n, i, j)),
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((m, o), dt),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             collective_id=collective_id, has_side_effects=True),
     )(*operands)

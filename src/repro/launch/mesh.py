@@ -11,6 +11,7 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 
@@ -55,8 +56,16 @@ def make_mesh_for(strategy: str, *, multi_pod: bool = False, data: int = 16,
 
 
 def make_small_mesh(strategy: str, data: int, mx: int, my: int,
-                    pods: int = 1):
-    """Scaled-down mesh for tests / weak-scaling studies on host devices.
+                    pods: int = 1, devices=None):
+    """Mesh over the first ``pods*data*mx*my`` of ``devices`` (default
+    ``jax.devices()``), for tests, weak-scaling studies and one TPU host.
+
+    The device order follows the TPU's physical interconnect (ICI torus):
+    :func:`_grid_devices` lays the hecaton (mx, my) grid on the chips'
+    (x, y) coordinates where the slice is exactly that grid, so every ring
+    over ``mx`` or ``my`` is a ring of neighbouring chips; otherwise
+    ``mesh_utils.create_device_mesh`` orders them.  Devices without
+    coordinates (CPU) keep enumeration order.
 
     ``pods > 1`` prepends a leading ``"pod"`` axis — the inter-package tier.
     Whether that axis is extra data parallelism or 1F1B pipeline stages is
@@ -66,16 +75,35 @@ def make_small_mesh(strategy: str, data: int, mx: int, my: int,
     batch-gradient) traffic crosses the slow tier.
     """
     n = pods * data * mx * my
-    devs = np.asarray(jax.devices()[:n])
-    if pods > 1:
-        if strategy == "hecaton":
-            return Mesh(devs.reshape(pods, data, mx, my),
-                        ("pod", "data", "mx", "my"))
-        return Mesh(devs.reshape(pods, data, mx * my),
-                    ("pod", "data", "model"))
+    devs = list(devices if devices is not None else jax.devices())[:n]
     if strategy == "hecaton":
-        return Mesh(devs.reshape(data, mx, my), ("data", "mx", "my"))
-    return Mesh(devs.reshape(data, mx * my), ("data", "model"))
+        shape, axes = (data, mx, my), ("data", "mx", "my")
+    else:
+        shape, axes = (data, mx * my), ("data", "model")
+    if pods > 1:
+        shape, axes = (pods,) + shape, ("pod",) + axes
+    grid = _grid_devices(devs, shape) if strategy == "hecaton" else None
+    if grid is None:
+        grid = mesh_utils.create_device_mesh(shape, devices=devs)
+    return Mesh(grid, axes)
+
+
+def _grid_devices(devs, shape):
+    """``devs`` as an array of ``shape`` whose last two axes are the chips'
+    physical x and y, or None unless they span exactly that x-y plane.
+
+    ``create_device_mesh`` lays a v5e 2x2 host out as one 4-chip ring
+    folded in two, which puts one axis of a 2x2 grid on the diagonals."""
+    coords = [getattr(d, "coords", None) for d in devs]
+    if any(c is None for c in coords):
+        return None
+    nx = 1 + max(c[0] for c in coords)
+    ny = 1 + max(c[1] for c in coords)
+    if (nx, ny) != tuple(shape[-2:]) or len(devs) != np.prod(shape):
+        return None
+    order = sorted(devs, key=lambda d: (getattr(d, "core_on_chip", 0),
+                                        d.coords[2], d.coords[0], d.coords[1]))
+    return np.asarray(order).reshape(shape)
 
 
 def pod_submeshes(mesh: Mesh):

@@ -28,7 +28,7 @@ def build_trace(rng, n_requests, vocab, prompt_lens, gen, arrival_every):
     return reqs
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true")
@@ -56,8 +56,17 @@ def main():
     ap.add_argument("--quant-kv", action="store_true",
                     help="store paged K/V as int8 + per-row fp32 scales "
                          "(docs/DESIGN.md §11)")
-    args = ap.parse_args()
+    return ap
 
+
+def run(args, *, on_prefill=None) -> dict:
+    """Serve the synthetic trace the command line ``args`` describe.
+
+    Computes in bf16 over fp32 master weights (ModelConfig.dtype_note).
+    ``on_prefill(request, last_logits)`` is called after each admission's
+    prefill with the logits at its last prompt position.  Returns the
+    record: ``finished`` (rid -> Finished), ``requests``, ``params``,
+    ``cfg``, ``warmup_s`` and the engine's ``stats``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -78,14 +87,15 @@ def main():
     pcfg = ParallelConfig(strategy="hecaton", data=1, model=1, mx=1, my=1)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
 
-    eng = DecodeEngine(cfg, pcfg, rc, params, pool, compute_dtype=jnp.float32,
+    eng = DecodeEngine(cfg, pcfg, rc, params, pool, compute_dtype=jnp.bfloat16,
                        eos_id=None if args.eos_id < 0 else args.eos_id,
                        method=args.sample, temperature=args.temperature,
                        top_p=args.top_p, seed=args.seed,
-                       quant_kv=args.quant_kv)
+                       quant_kv=args.quant_kv, on_prefill=on_prefill)
     t0 = time.perf_counter()
     eng.warmup(prompt_lens=prompt_lens)  # compile BEFORE the clock starts
-    print(f"warmup (jit) {time.perf_counter() - t0:.2f}s")
+    warmup_s = time.perf_counter() - t0
+    print(f"warmup (jit) {warmup_s:.2f}s")
 
     rng = np.random.default_rng(args.seed)
     reqs = build_trace(rng, args.requests, cfg.vocab_size, prompt_lens,
@@ -103,11 +113,20 @@ def main():
     print(f"pool             peak {eng.pool.peak_blocks_in_use}/"
           f"{pool.leasable_blocks} blocks  "
           f"(dense arena equiv {pool.dense_equiv_blocks} blocks / "
-          f"{dense_cache_bytes(cfg, args.slots, max_seq, jnp.float32)} B)")
+          f"{dense_cache_bytes(cfg, args.slots, max_seq, jnp.bfloat16)} B)")
     for rid in sorted(fin)[:4]:
         f = fin[rid]
         print(f"  rid={rid} plen={f.prompt_len} {f.reason:7s} "
               f"tokens={f.tokens[:10]}")
+    return {"finished": fin, "requests": reqs, "params": params, "cfg": cfg,
+            "warmup_s": warmup_s, "stats": eng.stats}
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    from repro import compat
+    compat.enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

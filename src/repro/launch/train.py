@@ -3,9 +3,12 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
         --steps 200 --batch 8 --seq 256 [--mesh-devices 8 --strategy hecaton]
 
-On this CPU container it runs single-device (or a small fake-device mesh via
---mesh-devices, spawned through XLA_FLAGS); on a real pod the same entry point
-picks up all devices.  Enables checkpointing + fault supervision.
+With ``JAX_PLATFORMS=cpu`` a multi-device mesh (--mesh-devices, or
+--pods > 1) runs on fake CPU devices, re-executed through XLA_FLAGS; on a TPU
+host the same entry point builds the mesh from its chips.  The step is
+compiled ahead of the first batch, so compile time and the step's memory
+analysis print before training starts.  Enables checkpointing + fault
+supervision.
 """
 
 from __future__ import annotations
@@ -16,7 +19,10 @@ import sys
 
 
 def _maybe_respawn(n: int):
-    if n > 1 and "XLA_FLAGS" not in os.environ:
+    """Re-exec with ``n`` fake CPU devices — only when the CPU platform is
+    requested (``JAX_PLATFORMS=cpu``); a chip host uses its real devices."""
+    cpu = os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+    if n > 1 and cpu and "XLA_FLAGS" not in os.environ:
         os.environ["XLA_FLAGS"] = \
             f"--xla_force_host_platform_device_count={n}"
         os.execv(sys.executable, [sys.executable] + sys.argv)
@@ -122,7 +128,7 @@ def _train_pipeline(cfg, pcfg, rc, mesh, args):
           f"final loss {h[-1][1]:.4f} (first {h[0][1]:.4f})")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true",
@@ -132,6 +138,11 @@ def main():
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--strategy", default="hecaton")
+    ap.add_argument("--overlap", default="none",
+                    choices=["none", "ring", "bidir", "fused"],
+                    help="NoP comm/compute overlap of the collectives "
+                         "(docs/DESIGN.md §1); fused runs the Pallas "
+                         "remote-DMA ring kernels on a TPU")
     ap.add_argument("--comm-dtype", default="bf16", choices=["bf16", "int8"],
                     help="ring-collective wire dtype: int8 quantizes each "
                          "hop's shard (docs/DESIGN.md §11)")
@@ -145,6 +156,10 @@ def main():
     ap.add_argument("--pod-role", default="data",
                     choices=("data", "pipeline"))
     ap.add_argument("--microbatches", type=int, default=1)
+    ap.add_argument("--remat", default="fusion",
+                    choices=["none", "fusion", "full"],
+                    help="activation-recompute policy (core/schedule.py): "
+                         "full keeps only block inputs for the backward")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--ckpt-keep", type=int, default=3)
@@ -188,14 +203,23 @@ def main():
     ap.add_argument("--no-rollback", action="store_true",
                     help="on divergence, restart WITHOUT retiring poisoned "
                          "checkpoints / blocklisting the poison window")
-    args = ap.parse_args()
-    _maybe_respawn(max(args.mesh_devices,
-                       args.pods * args.data * args.mx * args.my
-                       if args.pods > 1 else args.mesh_devices))
+    return ap
 
-    import dataclasses
+
+def run(args, *, devices=None, on_start=None) -> dict:
+    """Train as the command line ``args`` say; returns the run's record.
+
+    ``devices`` (default ``jax.devices()``) are the chips the mesh is built
+    from.  ``on_start(params, batch)`` is called once before the first step
+    with the initial fp32 master params and the first batch (a reference
+    check's hook: the step donates both).  The record holds ``history``
+    (per-step ``(step, loss)``), ``step_s`` (per-step seconds, compile
+    excluded), ``compile_s`` and the ``compiled`` step."""
+    import time
+
     import jax
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint.manager import make_manager
     from repro.config import (CheckpointConfig, ParallelConfig, RunConfig,
                               get_config, get_smoke_config)
@@ -215,36 +239,48 @@ def main():
                           model=args.mx * args.my, mx=args.mx, my=args.my,
                           pods=args.pods, pod_axis_role=args.pod_role,
                           microbatches=args.microbatches, zero1=True,
+                          remat=args.remat, overlap=args.overlap,
                           comm_dtype=args.comm_dtype)
     if args.mesh_devices > 1 or args.pods > 1:
         mesh = make_small_mesh(args.strategy, args.data, args.mx, args.my,
-                               pods=args.pods)
+                               pods=args.pods, devices=devices)
 
     if pcfg.pipeline_enabled:
         _train_pipeline(cfg, pcfg, rc, mesh, args)
-        return
+        return {}
 
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     opt_state = adamw.init(params)
-    if mesh is not None:
-        pspecs = SP.param_specs(params, mesh, pcfg)
-        pshard = SP.sharding_tree(pspecs, mesh)
-        params = jax.device_put(params, pshard)
-        ospecs = SP.opt_state_specs(pspecs, params, mesh, pcfg)
-        opt_state = jax.device_put(opt_state, SP.sharding_tree(ospecs, mesh))
-
-    gcfg = _guard_cfg(args)
-    ts = TS.build_train_step(cfg, pcfg, rc, mesh,
-                             compute_dtype=jnp.float32 if mesh is None
-                             else jnp.bfloat16, guard=gcfg)
-    ts = jax.jit(ts, donate_argnums=(0, 1))
-
     extras = {}
     if cfg.family == "vlm":
         extras["patches"] = (cfg.frontend_stub_len, cfg.d_model)
     if cfg.family == "audio":
         extras["frames"] = (cfg.frontend_stub_len, cfg.d_model)
     ds = SyntheticLM(cfg.vocab_size, args.seq, args.batch, extras=extras)
+    bshard = None
+    if mesh is not None:
+        pspecs = SP.param_specs(params, mesh, pcfg)
+        pshard = SP.sharding_tree(pspecs, mesh)
+        params = jax.device_put(params, pshard)
+        ospecs = SP.opt_state_specs(pspecs, params, mesh, pcfg)
+        oshard = SP.sharding_tree(ospecs, mesh)
+        opt_state = jax.device_put(opt_state, oshard)
+        bshard = SP.sharding_tree(
+            SP.batch_specs(mesh, pcfg, microbatched=False,
+                           keys=tuple(ds.batch_at(0)), seq_len=args.seq),
+            mesh)
+
+    gcfg = _guard_cfg(args)
+    # bf16 compute / fp32 master (ModelConfig.dtype_note) on any device count
+    ts = TS.build_train_step(cfg, pcfg, rc, mesh, compute_dtype=jnp.bfloat16,
+                             guard=gcfg)
+    if mesh is None:
+        ts = jax.jit(ts, donate_argnums=(0, 1))
+    else:
+        ts = jax.jit(ts, donate_argnums=(0, 1),
+                     in_shardings=(pshard, oshard, bshard),
+                     out_shardings=(pshard, oshard,
+                                    NamedSharding(mesh, P())))
 
     ccfg = CheckpointConfig(every=args.ckpt_every, keep=args.ckpt_keep,
                             async_=not args.ckpt_sync,
@@ -263,10 +299,18 @@ def main():
 
     tguard, wd, dix, stream = _guard_runtime(args, gcfg, args.ckpt_dir,
                                              start, ds.batch_at)
-    it = Prefetcher(stream)
+    batch0 = ds.batch_at(dix(start))
+    if on_start is not None:
+        on_start(params, batch0)
+    t0 = time.perf_counter()
+    compiled = ts.lower(params, opt_state, batch0).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"compiled train step in {compile_s:.1f}s: "
+          f"{compiled.memory_analysis()}")
+    it = Prefetcher(stream, sharding=bshard)
     state = {"params": params, "opt_state": opt_state}
     try:
-        state = train_loop.train(ts, state, it, start_step=start,
+        state = train_loop.train(compiled, state, it, start_step=start,
                                  num_steps=args.steps, ckpt=ckpt,
                                  ckpt_every=ccfg.every,
                                  timer=StepTimer(),
@@ -280,6 +324,18 @@ def main():
         ckpt.close()                 # train() already drained in-flight saves
     h = state["history"]
     print(f"final loss {h[-1][1]:.4f} (first {h[0][1]:.4f})")
+    return {"history": h, "step_s": state["step_s"], "compile_s": compile_s,
+            "compiled": compiled}
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    _maybe_respawn(max(args.mesh_devices,
+                       args.pods * args.data * args.mx * args.my
+                       if args.pods > 1 else args.mesh_devices))
+    from repro import compat
+    compat.enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -147,11 +147,11 @@ class PCtx:
         ``rng=None`` (or rate 0) is the deterministic path.  The mask is
         generated under GSPMD on the sharded operand, so no replicated
         [B,S,H] mask ever materializes.  The seq layout reproduces the
-        single-device mask bit-for-bit; on the 0.4.x jax series the
-        replicated megatron layout can draw a different (equally valid) mask
-        for the same key — old GSPMD's non-partitionable threefry lowering is
-        not bit-stable across program structure.  Keep rate and values are
-        exact in every layout."""
+        single-device mask bit-for-bit; the replicated megatron layout may
+        draw a different (equally valid) mask for the same key, since a
+        threefry lowering that GSPMD does not partition is not bit-stable
+        across program structure.  Keep rate and values are exact in every
+        layout."""
         if rate <= 0.0 or rng is None:
             return x
         return _L.dropout(self.canon(x), rate, rng)

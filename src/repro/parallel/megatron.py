@@ -156,7 +156,7 @@ def _col_seq(pctx, x, w, ring):
 def _col_ring(pctx, x, w, ring):
     # The custom_vjp wraps the shard_map calls from OUTSIDE: shard_map's own
     # transpose would conservatively psum cotangents over the unmentioned
-    # model axis (check_rep=False), double-counting the ring-reduced dx.
+    # model axis (check_vma=False), double-counting the ring-reduced dx.
     ax, n = ring
     d = _dax(pctx)
     a = pctx.ax

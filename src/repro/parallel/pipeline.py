@@ -28,11 +28,11 @@ Two layers live here:
    because inside a stage the world looks exactly like a single-pod run.
    Stage-boundary transfers move the canonical (seq-sharded) [B,S,H]
    residual shard-to-shard between neighbouring pods' sub-meshes via
-   ``jax.device_put`` — the point-to-point off-package hop.  (The jax 0.4.x
-   series cannot nest a pod-axis ``shard_map``/``ppermute`` around the
-   hecaton ops' own shard_maps, so the transfer is expressed as an explicit
-   reshard instead of a pod-axis collective-permute; on one global mesh the
-   two lower to the same device-to-device copies.)
+   ``jax.device_put`` — the point-to-point off-package hop.  (A pod-axis
+   ``shard_map``/``ppermute`` nested around the hecaton ops' own shard_maps
+   did not lower when this was written, so the transfer is expressed as an
+   explicit reshard instead of a pod-axis collective-permute; on one global
+   mesh the two lower to the same device-to-device copies.)
 
 Backward runs per-stage VJPs in the 1F1B order: a stage's backward
 *recomputes* its forward from the stashed boundary input (stage-granular

@@ -37,7 +37,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,8 +81,12 @@ class DecodeEngine:
                  compute_dtype=jnp.float32, eos_id: Optional[int] = None,
                  method: str = "greedy", temperature: float = 1.0,
                  top_p: float = 0.9, seed: int = 0,
-                 prompt_pad: Optional[int] = None, quant_kv: bool = False):
+                 prompt_pad: Optional[int] = None, quant_kv: bool = False,
+                 on_prefill: Optional[Callable] = None):
+        """``on_prefill(request, last_logits)`` is called after every
+        admission's prefill with the ``[1, 1, V]`` logits it sampled from."""
         self.cfg, self.pcfg, self.rc = cfg, pcfg, rc
+        self.on_prefill = on_prefill
         self.params = params
         self.pool = CachePool(cfg, pool, dtype=compute_dtype,
                               quant_kv=quant_kv)
@@ -199,6 +203,8 @@ class DecodeEngine:
                                        tokens, plen)
             last = jax.block_until_ready(last)
             self.stats["prefill_s"].append(time.perf_counter() - t0)
+            if self.on_prefill is not None:
+                self.on_prefill(req, last)
             self.pool.absorb_prefill(slot, tree)
             self.pool.commit_prefill(slot, len(req.prompt))
             st = _Running(req, slot, self._admit_seq, pending=-1)

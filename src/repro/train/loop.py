@@ -56,6 +56,7 @@ def train(train_step: Callable, state: Dict, data_iter, *,
     ``batch_at`` indices (docs/DESIGN.md §8)."""
     params, opt_state = state["params"], state["opt_state"]
     history = state.setdefault("history", [])
+    step_s = state.setdefault("step_s", [])      # per-step seconds
     if (ckpt is not None and injector is not None
             and hasattr(injector, "check_writer")
             and getattr(ckpt, "writer_fault", None) is None):
@@ -81,6 +82,7 @@ def train(train_step: Callable, state: Dict, data_iter, *,
         params, opt_state, metrics = train_step(params, opt_state, batch)
         jax.block_until_ready(metrics["loss"])
         dt = time.time() - t0
+        step_s.append(dt)
         if watchdog is not None:
             watchdog.disarm()
             watchdog.check()                # raises HangError if tripped

@@ -1,0 +1,90 @@
+"""The launchers' callable entry points, the compile-cache helper and the
+device grid, on the CPU at smoke widths."""
+
+import math
+import os
+from types import SimpleNamespace
+
+import jax
+import numpy as np
+import pytest
+
+from repro import compat
+from repro.launch import mesh as M
+from repro.launch import serve, train
+
+
+def test_train_run_smoke():
+    seen = []
+    args = train.build_parser().parse_args(
+        ["--smoke", "--steps", "3", "--batch", "2", "--seq", "32",
+         "--remat", "full"])
+    rec = train.run(args, on_start=lambda p, b: seen.append(b["tokens"].shape))
+    assert seen == [(2, 32)]
+    assert [s for s, _ in rec["history"]] == [0, 1, 2]
+    assert all(math.isfinite(loss) for _, loss in rec["history"])
+    assert len(rec["step_s"]) == 3 and rec["compile_s"] > 0
+    assert "tpu_custom_call" not in rec["compiled"].as_text()
+
+
+def test_serve_run_smoke():
+    prefilled = []
+    args = serve.build_parser().parse_args(
+        ["--smoke", "--requests", "3", "--gen", "4", "--prompt-lens", "8,20"])
+    rec = serve.run(args, on_prefill=lambda r, last: prefilled.append(
+        (r.rid, last.shape)))
+    assert sorted(rid for rid, _ in prefilled) == [0, 1, 2]
+    assert all(shape[:2] == (1, 1) for _, shape in prefilled)
+    assert {rid: len(f.tokens) for rid, f in rec["finished"].items()} == \
+        {0: 4, 1: 4, 2: 4}
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/cache"])
+def test_enable_compile_cache(monkeypatch, env):
+    was = jax.config.jax_compilation_cache_dir
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    try:
+        got = compat.enable_compile_cache()
+        if env is None:
+            assert got == str(compat.CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == got
+            # a fixed path at the checkout's root
+            assert compat.CACHE_DIR.name == ".jax_cache"
+            assert (compat.CACHE_DIR.parent / "src/repro/compat.py").exists()
+        else:
+            assert got == env
+            assert jax.config.jax_compilation_cache_dir == was
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+@pytest.mark.parametrize("platforms,respawns", [("cpu", True), ("", False),
+                                                ("tpu", False)])
+def test_maybe_respawn_only_on_cpu(monkeypatch, platforms, respawns):
+    # set-then-delete makes monkeypatch restore XLA_FLAGS however it started
+    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.delenv("XLA_FLAGS")
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    calls = []
+    monkeypatch.setattr(os, "execv", lambda *a: calls.append(a))
+    train._maybe_respawn(4)
+    assert bool(calls) == respawns
+    assert ("XLA_FLAGS" in os.environ) == respawns
+
+
+def _chips(nx, ny):
+    coords = [(x, y, 0) for y in range(ny) for x in range(nx)]
+    rng = np.random.default_rng(0)
+    return [SimpleNamespace(coords=c, core_on_chip=0)
+            for c in rng.permutation(np.array(coords, dtype=object))]
+
+
+def test_grid_devices_follow_chip_coordinates():
+    grid = M._grid_devices(_chips(2, 2), (1, 2, 2))
+    assert [[tuple(d.coords[:2]) for d in row] for row in grid[0]] == \
+        [[(0, 0), (0, 1)], [(1, 0), (1, 1)]]
+    assert M._grid_devices(_chips(4, 1), (1, 2, 2)) is None
+    assert M._grid_devices(jax.devices()[:1], (1, 1, 1)) is None
