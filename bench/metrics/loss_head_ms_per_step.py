@@ -1,0 +1,8 @@
+"""Device time per step of the train step's ``loss_head`` layer (its
+``jax.named_scope``), every phase, in ms (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(m):
+    return scopes.read_metric(m, "loss_head_ms_per_step")

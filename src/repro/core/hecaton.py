@@ -119,6 +119,7 @@ def _mm(x, w, precision=None):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("hecaton_linear_seq_scatter")
 def linear_seq_scatter(x: jax.Array, w: jax.Array, *, mesh: Optional[Mesh],
                        t_ax: str, h_ax: str,
                        data_axes: Tuple[str, ...] = ("data",),
@@ -160,6 +161,7 @@ def linear_seq_scatter(x: jax.Array, w: jax.Array, *, mesh: Optional[Mesh],
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("hecaton_mixer_in")
 def mixer_in(x: jax.Array, w: jax.Array, *, mesh: Optional[Mesh],
              t_ax: str, h_ax: str,
              data_axes: Tuple[str, ...] = ("data",),
@@ -195,6 +197,7 @@ def mixer_in(x: jax.Array, w: jax.Array, *, mesh: Optional[Mesh],
     )(x, w)
 
 
+@jax.named_scope("hecaton_mixer_out")
 def mixer_out(a: jax.Array, w: jax.Array, *, mesh: Optional[Mesh],
               t_ax: str, h_ax: str,
               data_axes: Tuple[str, ...] = ("data",),
@@ -251,6 +254,7 @@ def mixer_out(a: jax.Array, w: jax.Array, *, mesh: Optional[Mesh],
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("hecaton_ffn_block")
 def ffn_block(x, w1, w2, *, mesh, act_fn, t_ax: str, h_ax: str,
               data_axes: Tuple[str, ...] = ("data",),
               w1b=None, overlap: str = "none", comm_dtype: str = "bf16"):
@@ -349,6 +353,7 @@ def ffn_block(x, w1, w2, *, mesh, act_fn, t_ax: str, h_ax: str,
 EMBED_FUSED_VMAX = 2048
 
 
+@jax.named_scope("hecaton_embed_2d")
 def embed_2d(ids: jax.Array, table: jax.Array, *, mesh: Optional[Mesh],
              t_ax: str, h_ax: str, data_axes: Tuple[str, ...] = ("data",),
              compute_dtype=jnp.bfloat16, seq_sharded: bool = True,
@@ -428,6 +433,8 @@ def embed_2d(ids: jax.Array, table: jax.Array, *, mesh: Optional[Mesh],
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("loss_head")
+@jax.named_scope("hecaton_fused_lm_loss")
 def fused_lm_loss(x: jax.Array, w: jax.Array, labels: jax.Array,
                   loss_mask: Optional[jax.Array], *, mesh: Optional[Mesh],
                   t_ax: str, h_ax: str, data_axes: Tuple[str, ...] = ("data",),

@@ -63,6 +63,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                     ).astype(o_ref.dtype)
 
 
+@jax.named_scope("sdpa")
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
                     block_k: int = 128, interpret: bool = False) -> jax.Array:

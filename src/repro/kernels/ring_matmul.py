@@ -286,6 +286,7 @@ def _tile_mm_call(M: int, K: int, N: int, bm: int, bn: int, bk: int,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.dtype(out_dtype_name)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="ring_tile_matmul",
     )
 
 
@@ -989,6 +990,7 @@ def _ag_matmul_tpu(x, w, *, axis_name: str, dim: int, n: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             collective_id=collective_id, has_side_effects=True),
+        name="ring_ag_matmul",
     )(*operands)
     return out
 
@@ -1206,6 +1208,7 @@ def _matmul_rs_tpu(x, w, *, axis_name: str, scatter_dim: int, n: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             collective_id=collective_id, has_side_effects=True),
+        name="ring_matmul_rs",
     )(ids, x, w)
 
 
@@ -1376,6 +1379,7 @@ def _ag_matmul_contract_tpu(x, w, *, axis_name: str, n: int, out_dtype=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             collective_id=collective_id, has_side_effects=True),
+        name="ring_ag_matmul_contract",
     )(*operands)
     return out.reshape(b, t, o)
 

@@ -12,9 +12,8 @@ For every (arch x shape x mesh x strategy) cell:
   * builds the real train/prefill/decode step,
   * ``jax.jit(...).lower(**ShapeDtypeStructs).compile()`` on the production mesh
     (16x16 single pod / 2x16x16 multi-pod; hecaton refactors model=16 -> 4x4),
-  * prints ``memory_analysis()`` (proves it fits) and ``cost_analysis()``,
-  * extracts loop-scaled per-chip FLOPs / HBM bytes / collective bytes
-    (roofline/hlo.py) and writes one JSON per cell for EXPERIMENTS.md.
+  * writes one JSON per cell: lower and compile seconds,
+    ``memory_analysis()`` (proves it fits) and ``cost_analysis()``.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k --mesh single
@@ -39,7 +38,6 @@ from repro.models import lm
 from repro.optim import adamw
 from repro.parallel import sharding as shd
 from repro.parallel import specs as SP
-from repro.roofline import analysis as RA
 from repro.serve import step as serve_step
 from repro.train import step as train_step
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -160,12 +158,10 @@ def run_cell(arch, shape, strategy, multi_pod, out_dir):
         ma = compiled.memory_analysis()
         from repro.compat import cost_analysis_dict
         ca = cost_analysis_dict(compiled)
-        res = RA.from_compiled(
-            compiled, arch=arch, shape=shape, mesh_name=meta["mesh_name"],
-            strategy=strategy, chips=meta["chips"], cfg=meta["cfg"],
-            rc=meta["rc"], note=f"fsdp={meta['pcfg'].fsdp} "
-            f"micro={meta['pcfg'].microbatches}")
-        d = res.to_dict()
+        d = {"arch": arch, "shape": shape, "mesh": meta["mesh_name"],
+             "strategy": strategy, "chips": meta["chips"],
+             "note": f"fsdp={meta['pcfg'].fsdp} "
+                     f"micro={meta['pcfg'].microbatches}"}
         d["lower_s"] = round(t_lower, 1)
         d["compile_s"] = round(t_compile, 1)
         d["xla_cost_analysis"] = {k: ca.get(k) for k in
@@ -180,9 +176,7 @@ def run_cell(arch, shape, strategy, multi_pod, out_dir):
         os.makedirs(out_dir, exist_ok=True)
         with open(os.path.join(out_dir, tag + ".json"), "w") as f:
             json.dump(d, f, indent=1, default=str)
-        print(f"[OK] {tag}: compute={res.compute_s*1e3:.1f}ms "
-              f"mem={res.memory_s*1e3:.1f}ms coll={res.collective_s*1e3:.1f}ms "
-              f"bottleneck={res.bottleneck} "
+        print(f"[OK] {tag}: "
               f"args/chip={ma.argument_size_in_bytes/2**30:.2f}GiB "
               f"temp/chip={ma.temp_size_in_bytes/2**30:.2f}GiB "
               f"(lower {t_lower:.0f}s compile {t_compile:.0f}s)", flush=True)

@@ -59,6 +59,31 @@ def _guard_runtime(args, gcfg, ckpt_dir, start, batch_at):
     return tguard, wd, (lambda s: G.data_index(s, bl)), stream
 
 
+def _step_range(text: str):
+    """``A:B`` of --profile_steps: steps A..B-1."""
+    try:
+        a, b = (int(x) for x in text.split(":"))
+    except ValueError:
+        raise argparse.ArgumentTypeError(f"not A:B: {text!r}") from None
+    if not 0 <= a < b:
+        raise argparse.ArgumentTypeError(f"needs 0 <= A < B: {text!r}")
+    return a, b
+
+
+def _fold(train_steps, start: int, args):
+    """``train_steps(lo, hi)`` over steps [start, args.steps), the steps
+    --profile_steps names captured by ``jax.profiler.trace`` into
+    --profile_dir; returns the last call's state."""
+    if not args.profile_dir:
+        return train_steps(start, args.steps)
+    import jax
+    a, b = (min(max(x, start), args.steps) for x in args.profile_steps)
+    train_steps(start, a)
+    with jax.profiler.trace(args.profile_dir):
+        train_steps(a, b)
+    return train_steps(b, args.steps)
+
+
 def _train_pipeline(cfg, pcfg, rc, mesh, args):
     """1F1B pipeline path: per-pod stage state, host-side schedule executor.
 
@@ -111,12 +136,12 @@ def _train_pipeline(cfg, pcfg, rc, mesh, args):
                                              start, ds.batch_at)
     it = Prefetcher(stream)
     state = {"params": sparams, "opt_state": sopt}
+    timer = StepTimer()
     try:
-        state = train_loop.train(step, state, it, start_step=start,
-                                 num_steps=args.steps, ckpt=ckpt,
-                                 ckpt_every=ccfg.every, timer=StepTimer(),
-                                 guard=tguard, watchdog=wd,
-                                 data_index_fn=dix)
+        state = _fold(lambda lo, hi: train_loop.train(
+            step, state, it, start_step=lo, num_steps=hi, ckpt=ckpt,
+            ckpt_every=ccfg.every, timer=timer, guard=tguard, watchdog=wd,
+            data_index_fn=dix), start, args)
     finally:
         if wd is not None:
             wd.close()
@@ -203,6 +228,13 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--no-rollback", action="store_true",
                     help="on divergence, restart WITHOUT retiring poisoned "
                          "checkpoints / blocklisting the poison window")
+    ap.add_argument("--profile_dir", default=None,
+                    help="write a JAX profiler trace (.xplane.pb) of the "
+                         "--profile_steps steps here: device ops beside "
+                         "the loop's host spans (train/loop.py)")
+    ap.add_argument("--profile_steps", type=_step_range, default=(1, 3),
+                    metavar="A:B",
+                    help="steps A..B-1 to capture with --profile_dir")
     return ap
 
 
@@ -309,13 +341,12 @@ def run(args, *, devices=None, on_start=None) -> dict:
           f"{compiled.memory_analysis()}")
     it = Prefetcher(stream, sharding=bshard)
     state = {"params": params, "opt_state": opt_state}
+    timer = StepTimer()
     try:
-        state = train_loop.train(compiled, state, it, start_step=start,
-                                 num_steps=args.steps, ckpt=ckpt,
-                                 ckpt_every=ccfg.every,
-                                 timer=StepTimer(),
-                                 guard=tguard, watchdog=wd,
-                                 data_index_fn=dix)
+        state = _fold(lambda lo, hi: train_loop.train(
+            compiled, state, it, start_step=lo, num_steps=hi, ckpt=ckpt,
+            ckpt_every=ccfg.every, timer=timer, guard=tguard, watchdog=wd,
+            data_index_fn=dix), start, args)
     finally:
         if wd is not None:
             wd.close()

@@ -258,6 +258,7 @@ def quant_paged_gather(arena, scales, block_table, dtype):
 # core attention math (chunked over q blocks)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sdpa")
 def _sdpa(q, k, v, *, causal: bool, q_offset, kv_len=None, q_block: int = 1024):
     """q [B,Sq,nh,dh]; k,v [B,Sk,nh,dh] (kv already repeated to nh).
 
@@ -306,6 +307,7 @@ def _repeat_kv(k, n_rep: int):
     return jnp.repeat(k, n_rep, axis=2)
 
 
+@jax.named_scope("sdpa")
 def _sdpa_grouped_decode(q, k, v, *, kv_len):
     """Decode-step attention WITHOUT repeating KV (GQA grouped einsum).
 
@@ -327,6 +329,7 @@ def _sdpa_grouped_decode(q, k, v, *, kv_len):
 # GQA attention block
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def apply_attn(pctx, cfg: ModelConfig, p, x, *, positions, causal: bool = True,
                cache: Optional[KVCache] = None, layout=None,
                q_block: int = 1024) -> Tuple[jax.Array, Optional[KVCache]]:
@@ -423,6 +426,7 @@ def apply_attn(pctx, cfg: ModelConfig, p, x, *, positions, causal: bool = True,
     return y, new_cache
 
 
+@jax.named_scope("attention")
 def apply_cross_attn(pctx, cfg: ModelConfig, p, x, memory_kv, *, layout=None):
     """Whisper cross-attention: q from decoder x, k/v precomputed from encoder."""
     dh = cfg.resolved_head_dim
@@ -450,6 +454,7 @@ def cross_kv(pctx, cfg: ModelConfig, p, memory):
 # MLA (minicpm3 / deepseek style)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def apply_mla(pctx, cfg: ModelConfig, p, x, *, positions,
               cache: Optional[MLACache] = None, layout=None, q_block: int = 1024):
     m = cfg.mla

@@ -385,6 +385,7 @@ def _loss_mask(cfg, batch):
     return mask
 
 
+@jax.named_scope("loss_head")
 def head_loss(pctx, cfg: ModelConfig, params, hidden, labels, *, mask=None,
               compute_dtype=jnp.bfloat16):
     """Post-final-norm hidden states -> mean masked NLL.

@@ -44,6 +44,7 @@ def init_mlp(cfg: ModelConfig, key):
     return p
 
 
+@jax.named_scope("ffn")
 def apply_mlp(pctx, cfg: ModelConfig, p, x):
     act = L.ACTIVATIONS[cfg.mlp_kind]
     return pctx.ffn(x, p["w1"], p["w2"], act, p.get("w1b"))
@@ -141,6 +142,7 @@ def moe_aux_losses(probs, idx_onehot_mean=None):
     return E * jnp.sum(me * me)
 
 
+@jax.named_scope("ffn")
 def apply_moe(pctx, cfg: ModelConfig, p, x):
     """x [B,S,H] canonical -> y canonical (+ aux loss scalar)."""
     mc = cfg.moe

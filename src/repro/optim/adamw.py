@@ -81,6 +81,7 @@ def guard_predicate(gnorm, ewma, guard):
     return finite & ~spike, finite
 
 
+@jax.named_scope("optimizer")
 def update(params, grads, state: AdamState, rc: RunConfig,
            total_steps: int = 10_000, *,
            grad_norm=None, guard=None) -> Tuple[Any, AdamState, Dict]:

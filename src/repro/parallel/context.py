@@ -132,6 +132,7 @@ class PCtx:
     # ------------------------------------------------------------------
     # shard-local residual-stream ops (norm / dropout run on 1/n_t tokens)
     # ------------------------------------------------------------------
+    @jax.named_scope("norm")
     def norm(self, kind: str, params, x, eps: float = 1e-6):
         """Pre-norm on the canonical residual layout.
 
@@ -224,6 +225,7 @@ class PCtx:
             return meg.row_parallel(self, y, w)
         return _einsum(y, w)
 
+    @jax.named_scope("embed")
     def embed(self, table, ids, compute_dtype):
         """Vocab-parallel embedding lookup (core/hecaton.embed_2d).
 

@@ -16,6 +16,11 @@ surfaces any writer error; on failure the supervisor aborts them instead
 (``run_supervised(ckpt=...)``) so a restart never resumes from a
 half-published step.
 
+Each step is a ``StepTraceAnnotation("train")`` holding host spans
+``next_batch``, ``dispatch``, ``loss_sync``, ``guard`` and ``ckpt_save``, so
+a profile (``launch/train.py --profile_dir``) puts the device's idle gaps
+down to what the host was doing.
+
 The loop is agnostic to HOW the step runs: the single-program jitted step
 (train/step.py) and the 1F1B pipeline orchestrator
 (parallel/pipeline.build_pipeline_train_step) both fold ``(params,
@@ -31,6 +36,7 @@ from typing import Callable, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.runtime.fault import FailureInjector, StepTimer
@@ -73,41 +79,49 @@ def train(train_step: Callable, state: Dict, data_iter, *,
         # same torn window, process-level failure modes
         ckpt.proc_fault = injector.proc_fault
     for step in range(start_step, num_steps):
-        batch = next(data_iter)
-        if injector is not None:
-            injector.check(step)
-        if watchdog is not None:
-            watchdog.arm(step)
-        t0 = time.time()
-        params, opt_state, metrics = train_step(params, opt_state, batch)
-        jax.block_until_ready(metrics["loss"])
-        dt = time.time() - t0
-        step_s.append(dt)
-        if watchdog is not None:
-            watchdog.disarm()
-            watchdog.check()                # raises HangError if tripped
-        if timer is not None and timer.record(dt) and on_straggler:
-            on_straggler(step, timer)
-        # per-step history: the loss is already a synced scalar (the
-        # block_until_ready above), so recording every step costs one float
-        # append — and restart-exactness tests / the guard see the full
-        # trajectory, not a log_every subsample
-        loss = float(metrics["loss"])
-        history.append((step, loss))
-        if step % log_every == 0 or step == num_steps - 1:
-            log_fn(f"step {step:5d} loss {loss:.4f} "
-                   f"gnorm {float(metrics.get('grad_norm', 0)):.3f} "
-                   f"{dt*1e3:.0f}ms")
-        if guard is not None:
-            # before the boundary save: a DivergenceError here must not
-            # let the poisoned state publish
-            guard.observe(step, loss, metrics,
-                          data_index=(data_index_fn(step)
-                                      if data_index_fn else step))
-        if ckpt is not None and (step + 1) % ckpt_every == 0:
-            # non-blocking on AsyncCheckpointManager; = save() on the sync one
-            ckpt.save_async(step + 1, {"params": params,
-                                       "opt_state": opt_state})
+        with StepTraceAnnotation("train", step_num=step):
+            with TraceAnnotation("next_batch"):
+                batch = next(data_iter)
+            if injector is not None:
+                injector.check(step)
+            if watchdog is not None:
+                watchdog.arm(step)
+            t0 = time.time()
+            with TraceAnnotation("dispatch"):
+                params, opt_state, metrics = train_step(params, opt_state,
+                                                        batch)
+            with TraceAnnotation("loss_sync"):
+                jax.block_until_ready(metrics["loss"])
+            dt = time.time() - t0
+            step_s.append(dt)
+            if watchdog is not None:
+                watchdog.disarm()
+                watchdog.check()                # raises HangError if tripped
+            if timer is not None and timer.record(dt) and on_straggler:
+                on_straggler(step, timer)
+            # per-step history: the loss is already a synced scalar (the
+            # block_until_ready above), so recording every step costs one
+            # float append — and restart-exactness tests / the guard see the
+            # full trajectory, not a log_every subsample
+            loss = float(metrics["loss"])
+            history.append((step, loss))
+            if step % log_every == 0 or step == num_steps - 1:
+                log_fn(f"step {step:5d} loss {loss:.4f} "
+                       f"gnorm {float(metrics.get('grad_norm', 0)):.3f} "
+                       f"{dt*1e3:.0f}ms")
+            if guard is not None:
+                # before the boundary save: a DivergenceError here must not
+                # let the poisoned state publish
+                with TraceAnnotation("guard"):
+                    guard.observe(step, loss, metrics,
+                                  data_index=(data_index_fn(step)
+                                              if data_index_fn else step))
+            if ckpt is not None and (step + 1) % ckpt_every == 0:
+                # non-blocking on AsyncCheckpointManager; = save() on the
+                # sync one
+                with TraceAnnotation("ckpt_save"):
+                    ckpt.save_async(step + 1, {"params": params,
+                                               "opt_state": opt_state})
     if ckpt is not None:
         ckpt.wait_until_finished()          # drain async writes; raise errors
     state.update(params=params, opt_state=opt_state)

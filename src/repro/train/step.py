@@ -78,14 +78,17 @@ def build_train_step(cfg: ModelConfig, pcfg: ParallelConfig, rc: RunConfig,
         def mb_body(carry, mb):
             gsum, lsum, asum = carry
             (loss, metrics), g = grad_fn(params, mb)
-            g = zero.compress_grads(g, pcfg.grad_reduce_dtype)
-            gsum = jax.tree.map(lambda a, b: a + b.astype(a.dtype), gsum, g)
+            with jax.named_scope("grad_accum"):
+                g = zero.compress_grads(g, pcfg.grad_reduce_dtype)
+                gsum = jax.tree.map(lambda a, b: a + b.astype(a.dtype),
+                                    gsum, g)
             return (gsum, lsum + metrics["loss"], asum + metrics["aux"]), None
 
         gzero = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
         (gsum, lsum, asum), _ = lax.scan(
             mb_body, (gzero, jnp.zeros(()), jnp.zeros(())), mbs)
-        grads = jax.tree.map(lambda g: g / n_micro, gsum)
+        with jax.named_scope("grad_accum"):
+            grads = jax.tree.map(lambda g: g / n_micro, gsum)
         new_params, new_opt, om = adamw.update(params, grads, opt_state, rc,
                                                total_steps, guard=guard)
         metrics = {"loss": lsum / n_micro, "aux": asum / n_micro, **om}

@@ -88,3 +88,27 @@ def test_grid_devices_follow_chip_coordinates():
         [[(0, 0), (0, 1)], [(1, 0), (1, 1)]]
     assert M._grid_devices(_chips(4, 1), (1, 2, 2)) is None
     assert M._grid_devices(jax.devices()[:1], (1, 1, 1)) is None
+
+
+def test_train_run_profile_holds_the_step_spans(tmp_path):
+    from jax.profiler import ProfileData
+
+    args = train.build_parser().parse_args(
+        ["--smoke", "--steps", "4", "--batch", "2", "--seq", "32",
+         "--profile_dir", str(tmp_path), "--profile_steps", "1:3"])
+    rec = train.run(args)
+    assert [s for s, _ in rec["history"]] == [0, 1, 2, 3]
+    found = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert len(found) == 1
+    names = [e.name for p in ProfileData.from_file(str(found[0])).planes
+             if p.name.startswith("/host:") for line in p.lines
+             for e in line.events]
+    assert names.count("train") == 2
+    for span in ("next_batch", "dispatch", "loss_sync"):
+        assert names.count(span) == 2, span
+
+
+@pytest.mark.parametrize("text", ["3", "3:3", "a:b", "-1:2"])
+def test_profile_steps_must_be_a_range(text):
+    with pytest.raises(SystemExit):
+        train.build_parser().parse_args(["--profile_steps", text])

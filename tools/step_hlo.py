@@ -1,0 +1,58 @@
+"""Print a benchmark cell's train step as the TPU compiler builds it for a
+described v5e chip, with its metadata taken out.  Needs no chip.
+
+    JAX_PLATFORMS=cpu python tools/step_hlo.py --workload qwen3-0.6b.train_4k \
+        [--root CHECKOUT] | sha256sum
+
+Two checkouts whose steps print the same text compile to the same program:
+a change that only names operations (``jax.named_scope``) leaves it as it
+was.  Taken out: the debug tables between the ``HloModule`` line and the
+first computation (source files, functions, stack frames) and every
+instruction's ``metadata={...}``.
+"""
+
+import argparse
+import os
+import re
+import sys
+from pathlib import Path
+
+
+def strip_metadata(hlo_text: str) -> str:
+    lines = hlo_text.splitlines()
+    first = next(i for i, line in enumerate(lines)
+                 if line.startswith(("%", "ENTRY")))
+    body = "\n".join(lines[:1] + lines[first:])
+    return re.sub(r",? metadata=\{[^}]*\}", "", body) + "\n"
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--root", default=str(Path(__file__).resolve().parents[1]),
+                    help="the checkout whose step to compile")
+    args = ap.parse_args(argv)
+    root = Path(args.root).resolve()
+    sys.path[:0] = [str(root), str(root / "src")]
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from bench import spec, train_cell
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cell = spec.cell(args.workload, root=root)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    prog = train_cell.Program(cell, list(topo.devices)[:cell.chips])
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    p_abs, o_abs = jax.eval_shape(prog._init, key)
+    b_abs = jax.eval_shape(prog._batch, key, jnp.int32(0))
+    text = prog._step.lower(p_abs, o_abs, b_abs).compile().as_text()
+    sys.stdout.write(strip_metadata(text))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
