@@ -1,3 +1,4 @@
-# Pallas kernels.  ring_matmul.py (the fused NoP ring collectives) is on the
-# model path; matmul.py, flash_attention.py and ssd.py are standalone kernels
-# checked against ref.py by tests/test_kernels.py.
+# Pallas kernels.  ring_matmul.py (the fused NoP ring collectives) and
+# flash_attention.py (the attention core of a single-chip train step) are on
+# the model path; matmul.py and ssd.py are standalone kernels checked against
+# ref.py by tests/test_kernels.py.

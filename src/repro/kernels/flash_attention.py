@@ -1,101 +1,75 @@
-"""Flash attention (online softmax) Pallas TPU kernel.
+"""Flash attention for TPU, forward and backward: the S x S scores of a
+self-attention never leave VMEM.
 
-TPU-native rethinking of the standard GPU flash algorithm: instead of warp-level
-shuffles, the sequential TPU grid carries running (max, sum, acc) statistics in
-VMEM scratch across the KV-block axis; the MXU consumes (q_block x kv_block)
-tiles.  Causal masking skips fully-masked KV blocks via pl.when.  GQA is
-supported by mapping multiple q-heads onto one kv-head index (no KV repeat —
-the memory argument from docs/DESIGN.md §4).
+The kernel is the splash attention kernel that ships with JAX
+(``jax.experimental.pallas.ops.tpu.splash_attention``): an online-softmax
+forward that also returns the log-sum-exp, a dQ kernel and a dK/dV kernel,
+joined by ``jax.custom_vjp``.  Blocks the mask leaves empty are skipped, in
+the MXU and in the DMA; GQA maps each group of q-heads onto its kv-head, so
+K and V are never repeated (the memory argument of docs/DESIGN.md §4).
+Operands enter the MXU in their own dtype (bf16 on the model path) and
+accumulate in float32; the running max, sum and log-sum-exp are float32.
 
-Grid: (batch*q_heads, Sq/bq, Sk/bk), KV axis innermost.
+``models/attention.py`` puts :func:`flash_attention` on the causal
+self-attention of a single-device training step; :func:`refusal` is the
+kernel's part of that dispatch.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import splash_attention as SA
 
-NEG_INF = -1e30
+LANE = 128          # q/kv blocks and the head dim tile by the lane width
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  scale: float, causal: bool, bq: int, bk: int, n_k: int):
-    kv_i = pl.program_id(2)
+def refusal(seq: int, head_dim: int) -> Optional[str]:
+    """Why the kernel's tiling refuses a sequence/head width, or None."""
+    if seq % LANE or head_dim % LANE:
+        return f"seq {seq} or head dim {head_dim} not a multiple of {LANE}"
+    return None
 
-    @pl.when(kv_i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_i = pl.program_id(1)
+def _block(seq: int, pref: int) -> int:
+    """The largest multiple of LANE up to ``pref`` that divides ``seq``."""
+    b = min(pref, seq) // LANE * LANE
+    while seq % b:
+        b -= LANE
+    return b
 
-    def _step():
-        q = q_ref[0].astype(jnp.float32)                     # [bq, dh]
-        k = k_ref[0].astype(jnp.float32)                     # [bk, dh]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = q_i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = kv_i * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v_ref[0].astype(jnp.float32),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
 
-    if causal:   # skip fully-masked KV blocks entirely
-        pl.when(kv_i * bk <= q_i * bq + bq - 1)(_step)
-    else:
-        _step()
+def block_sizes(seq: int) -> SA.BlockSizes:
+    """One q and kv block for every kernel: half the sequence, so that the
+    causal mask leaves a block to skip, and at most 1024.  A sweep of 128,
+    512 and 1024 on a v5e found 1024 fastest at S=4096 and 512 at S=1024
+    (PERF.md section 6)."""
+    b = _block(seq, max(LANE, min(1024, seq // 2)))
+    return SA.BlockSizes(block_q=b, block_kv=b, block_kv_compute=b,
+                         block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+                         block_q_dq=b, block_kv_dq=b)
 
-    @pl.when(kv_i == n_k - 1)
-    def _done():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                    ).astype(o_ref.dtype)
+
+@functools.lru_cache(maxsize=None)
+def _kernel(heads: int, seq: int, causal: bool, interpret: bool):
+    mask = (SA.CausalMask if causal else SA.FullMask)((seq, seq))
+    # the block tables are constants, made outside any trace so that the
+    # cached kernel holds arrays and not one trace's tracers
+    with jax.ensure_compile_time_eval():
+        return SA.make_splash_mha(SA.MultiHeadMask([mask] * heads),
+                                  block_sizes=block_sizes(seq), head_shards=1,
+                                  q_seq_shards=1, interpret=interpret)
 
 
 @jax.named_scope("sdpa")
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False) -> jax.Array:
-    """q [B,nh,Sq,dh]; k,v [B,nkv,Sk,dh]; nh % nkv == 0.  Returns [B,nh,Sq,dh]."""
-    B, nh, Sq, dh = q.shape
-    _, nkv, Sk, _ = k.shape
-    assert nh % nkv == 0
-    g = nh // nkv
-    bq, bk = min(block_q, Sq), min(block_k, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0
-    qf = q.reshape(B * nh, Sq, dh)
-    kf = k.reshape(B * nkv, Sk, dh)
-    vf = v.reshape(B * nkv, Sk, dh)
-    grid = (B * nh, Sq // bq, Sk // bk)
-    scale = dh ** -0.5
-
-    kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, n_k=grid[2])
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bk, dh), lambda h, i, j, g=g: (h // g, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda h, i, j, g=g: (h // g, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, dh), lambda h, i, j: (h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * nh, Sq, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),     # running max
-            pltpu.VMEM((bq, 1), jnp.float32),     # running denom
-            pltpu.VMEM((bq, dh), jnp.float32),    # running accumulator
-        ],
-        interpret=interpret,
-    )(qf, kf, vf).reshape(B, nh, Sq, dh)
+                    causal: bool = True, interpret: bool = False) -> jax.Array:
+    """q [B,nh,S,dh]; k,v [B,nkv,S,dh], nh % nkv == 0.  Returns [B,nh,S,dh]
+    in q's dtype, softmax(q k^T / sqrt(dh)) v."""
+    B, nh, S, dh = q.shape
+    assert k.shape[2] == S and nh % k.shape[1] == 0, (q.shape, k.shape)
+    q = (q.astype(jnp.float32) * dh ** -0.5).astype(q.dtype)
+    return jax.vmap(_kernel(nh, S, causal, interpret))(q, k, v)

@@ -248,6 +248,7 @@ def run(args, *, devices=None, on_start=None) -> dict:
     (per-step ``(step, loss)``), ``step_s`` (per-step seconds, compile
     excluded), ``compile_s`` and the ``compiled`` step."""
     import time
+    from collections import Counter
 
     import jax
     import jax.numpy as jnp
@@ -262,7 +263,7 @@ def run(args, *, devices=None, on_start=None) -> dict:
     from repro.runtime.fault import StepTimer
     from repro.train import loop as train_loop
     from repro.train import step as TS
-    from repro.models import lm
+    from repro.models import attention as ATT, lm
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rc = RunConfig("custom", "train", args.seq, args.batch, lr=args.lr)
@@ -335,10 +336,15 @@ def run(args, *, devices=None, on_start=None) -> dict:
     if on_start is not None:
         on_start(params, batch0)
     t0 = time.perf_counter()
+    ATT.sdpa_paths.clear()
     compiled = ts.lower(params, opt_state, batch0).compile()
     compile_s = time.perf_counter() - t0
     print(f"compiled train step in {compile_s:.1f}s: "
           f"{compiled.memory_analysis()}")
+    paths = Counter((path, reason) for path, _, reason in ATT.sdpa_paths)
+    print("attention cores traced: " + ", ".join(
+        f"{n} {path}" + (f" ({reason})" if reason else "")
+        for (path, reason), n in sorted(paths.items(), key=str)))
     it = Prefetcher(stream, sharding=bshard)
     state = {"params": params, "opt_state": opt_state}
     timer = StepTimer()

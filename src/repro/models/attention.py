@@ -2,8 +2,12 @@
 cross-attention (whisper).  All projections route through PCtx so the Hecaton
 §IV-C dataflow (sequence gathered, heads sharded, AG/RS only) applies uniformly.
 
-Long sequences use a q-block-chunked softmax (``lax.scan``) so the [S,S] score
-matrix is never materialized — the jnp analogue of kernels/flash_attention.py.
+The attention core of a causal self-attention over whole sequences on one
+device (a training step) is the flash kernel of kernels/flash_attention.py
+where the step is lowered for TPU.  Every other core (KV caches, cross- and
+non-causal attention, MLA, a mesh, other platforms) is ``_sdpa``, a softmax
+chunked over q blocks (``lax.map``) so that no [Sq,Sk] score matrix is built
+whole, or ``_sdpa_grouped_decode``.  ``sdpa_paths`` records which was taken.
 """
 
 from __future__ import annotations
@@ -16,6 +20,7 @@ from jax import lax
 
 from repro.config import ModelConfig
 from repro.core import quant as QU
+from repro.kernels import flash_attention as FA
 from repro.models import layers as L
 
 NEG_INF = -1e30
@@ -255,8 +260,45 @@ def quant_paged_gather(arena, scales, block_table, dtype):
 
 
 # ---------------------------------------------------------------------------
-# core attention math (chunked over q blocks)
+# core attention math: the flash kernel, or chunked over q blocks
 # ---------------------------------------------------------------------------
+
+# (path, q shape, reason) of every attention core traced, appended at trace
+# time: path "flash" (the kernel where the step is lowered for TPU, _sdpa on
+# other platforms) or "jnp" (_sdpa, _sdpa_grouped_decode), with the reason the
+# kernel was not taken.  A caller that must see the paths clears it first.
+sdpa_paths: list = []
+
+
+def _flash_ok(pctx, q, k, v, *, causal: bool, cache) -> bool:
+    """Whether the flash kernel takes this core (q [B,Sq,nh,dh], k [B,Sk,..],
+    v [B,Sk,..,dv]), recorded in ``sdpa_paths``.  It takes a causal
+    self-attention over whole sequences on one device: no KV cache (decode,
+    prefill-continue), no mesh (its head axes would need a shard_map), v as
+    wide as q and k, and the kernel's tiling."""
+    reason = ("kv cache" if cache is not None else
+              "not causal" if not causal else
+              "Sq != Sk" if q.shape[1] != k.shape[1] else
+              "mesh" if pctx.mesh is not None else
+              "v head dim != qk head dim" if v.shape[-1] != q.shape[-1] else
+              FA.refusal(q.shape[1], q.shape[-1]))
+    sdpa_paths.append(("jnp" if reason else "flash", tuple(q.shape), reason))
+    return reason is None
+
+
+def _causal_self_attention(q, k, v, q_block: int):
+    """q [B,S,nh,dh]; k,v [B,S,nkv,dh] (GQA, not repeated).  The flash kernel
+    where the step is lowered for TPU, ``_sdpa`` on any other platform."""
+    def flash(q, k, v):
+        t = lambda x: x.transpose(0, 2, 1, 3)       # noqa: E731
+        return t(FA.flash_attention(t(q), t(k), t(v)))
+
+    def chunked(q, k, v):
+        g = q.shape[2] // k.shape[2]
+        return _sdpa(q, _repeat_kv(k, g), _repeat_kv(v, g), causal=True,
+                     q_offset=0, q_block=q_block)
+    return lax.platform_dependent(q, k, v, tpu=flash, default=chunked)
+
 
 @jax.named_scope("sdpa")
 def _sdpa(q, k, v, *, causal: bool, q_offset, kv_len=None, q_block: int = 1024):
@@ -394,7 +436,10 @@ def apply_attn(pctx, cfg: ModelConfig, p, x, *, positions, causal: bool = True,
         k, v = kc, vc
         kv_len, q_off = new_cache.length, cache.length
 
-    if cache is not None and S == 1:
+    if _flash_ok(pctx, q, k, v, causal=causal, cache=cache):
+        o = _causal_self_attention(q, k.astype(q.dtype), v.astype(q.dtype),
+                                   q_block)
+    elif cache is not None and S == 1:
         # decode: grouped GQA, KV cache stays kv-head-sharded
         kv_lay = pctx.attn_layout(nkv, B)
         ba = None
@@ -436,6 +481,7 @@ def apply_cross_attn(pctx, cfg: ModelConfig, p, x, memory_kv, *, layout=None):
     hspec = pctx.heads_spec(layout) if layout is not None else None
     q = pctx.constraint(q, hspec)
     k, v = memory_kv
+    _flash_ok(pctx, q, k, v, causal=False, cache=None)
     k = pctx.constraint(_repeat_kv(k.astype(q.dtype), nh // nkv), hspec)
     v = pctx.constraint(_repeat_kv(v.astype(q.dtype), nh // nkv), hspec)
     o = _sdpa(q, k, v, causal=False, q_offset=jnp.zeros((), jnp.int32))
@@ -535,6 +581,7 @@ def apply_mla(pctx, cfg: ModelConfig, p, x, *, positions,
             [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                       (*k_nope.shape[:3], dr))], axis=-1)
         qq = jnp.concatenate([q_nope, q_rope], axis=-1)
+        _flash_ok(pctx, qq, k, vv, causal=True, cache=cache)
         qq = pctx.constraint(qq, hspec)
         k = pctx.constraint(k, hspec)
         # Perf iteration 3b tried passing v at its native 64-dim head (saves

@@ -70,7 +70,7 @@ class OpCost:
     @property
     def hbm_bytes_kernel_adjusted(self) -> float:
         """HBM bytes assuming attention runs as a fused flash kernel: the
-        [*, Sq, Sk] score/prob intermediates the jnp fallback materializes
+        [*, Sq, Sk] score/prob intermediates the jnp ``_sdpa`` materializes
         never leave VMEM in kernels/flash_attention.py, so they are excluded
         (their Q/K/V/O boundary tensors remain counted)."""
         return self.hbm_bytes - self.s2_bytes

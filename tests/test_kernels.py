@@ -59,13 +59,54 @@ def test_flash_attention_sweep(B, nh, nkv, S, dh, causal, dtype):
     q = jax.random.normal(k1, (B, nh, S, dh), dtype)
     k = jax.random.normal(k2, (B, nkv, S, dh), dtype)
     v = jax.random.normal(k3, (B, nkv, S, dh), dtype)
-    o = FA.flash_attention(q, k, v, causal=causal, block_q=128, block_k=128,
-                           interpret=True)
+    o = FA.flash_attention(q, k, v, causal=causal, interpret=True)
     ref = R.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(ref, np.float32),
                                **(_tol(dtype) if dtype == jnp.bfloat16
                                   else dict(rtol=2e-3, atol=2e-3)))
+
+
+def _heads_major(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("S", [256, 512])
+@pytest.mark.parametrize("nh,nkv", [(16, 8), (4, 1)])
+@pytest.mark.parametrize("part", ["fwd", "grad"])
+def test_flash_matches_sdpa(nh, nkv, S, part):
+    """The model's kernel path against the chunked jnp ``_sdpa`` it replaces
+    on a training step: causal GQA in bf16, K/V not repeated for the kernel;
+    the output, or the gradients into q, k and v."""
+    from repro.models import attention as ATT
+    B, dh = 2, 128
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, S, nh, dh), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, nkv, dh), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, nkv, dh), jnp.bfloat16)
+    do = jax.random.normal(ks[3], (B, S, nh, dh), jnp.float32)
+
+    def flash(q, k, v):
+        return _heads_major(FA.flash_attention(
+            _heads_major(q), _heads_major(k), _heads_major(v),
+            interpret=True))
+
+    def sdpa(q, k, v):
+        g = nh // nkv
+        return ATT._sdpa(q, jnp.repeat(k, g, 2), jnp.repeat(v, g, 2),
+                         causal=True, q_offset=0, q_block=128)
+
+    def run(att):
+        if part == "fwd":
+            return [att(q, k, v)]
+        return jax.grad(lambda *a: jnp.sum(att(*a).astype(jnp.float32) * do),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    for got, want in zip(run(flash), run(sdpa)):
+        assert got.dtype == want.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   **_tol(jnp.bfloat16))
 
 
 @pytest.mark.parametrize("b,S,nh,dh,g,ds,chunk", [

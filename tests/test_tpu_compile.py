@@ -1,16 +1,19 @@
-"""The TPU compiler accepts the ring kernels at qwen3-0.6b's per-die widths.
+"""The TPU compiler accepts the model path's Pallas kernels at qwen3-0.6b's
+widths.
 
 Each test compiles for a described (not attached) v5e 2x2 host: the fused
 ring collectives of kernels/ring_matmul.py on the Hecaton mx=my=2 grid,
-forward and ``jax.grad``, bf16 and int8 wire, and the plain tile matmul,
-then checks that the compiled program holds the Pallas kernel
-(``tpu_custom_call``).  Nothing runs, so this says nothing about results or
+forward and ``jax.grad``, bf16 and int8 wire, the plain tile matmul, and a
+one-chip train step with the flash attention kernel of
+kernels/flash_attention.py, then checks that the compiled program holds the
+Pallas kernel (``tpu_custom_call``).  Nothing runs, so this says nothing about results or
 speed; it catches what interpret mode cannot (tile alignment, VMEM, ref
 shapes).  The topology is described only inside the module fixture, after a
 test has started: only one process may load the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +21,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro import compat
+from repro.kernels import flash_attention as FA
 from repro.kernels import ring_matmul as RM
 
 D, F, S = 1024, 3072, 1024     # qwen3-0.6b d_model, d_ff; seq of the 2x2 smoke
@@ -122,3 +126,84 @@ def test_pick_block_is_tpu_tileable():
             b = RM.pick_block(dim, pref, align)
             assert dim % b == 0
             assert b == dim or (b % align == 0 and b <= pref), (dim, b)
+
+
+# ---------------------------------------------------------------------------
+# flash attention on the one-chip train step
+# ---------------------------------------------------------------------------
+
+def _train_step(topo):
+    """qwen3-0.6b cut to 2 layers, seq 4096 x batch 2, full remat, compiled
+    for one described v5e: (compiled text, temporaries, traced paths)."""
+    from repro.config import ParallelConfig, RunConfig, get_config
+    from repro.models import attention as ATT
+    from repro.models import lm
+    from repro.optim import adamw
+    from repro.train import step as TS
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = get_config("qwen3-0.6b").scaled(num_layers=2)
+    pcfg = ParallelConfig(data=1, model=1, mx=1, my=1, microbatches=1,
+                          remat="full")
+    step = TS.build_train_step(cfg, pcfg, RunConfig("t", "train", 4096, 2),
+                               None)
+    p = jax.eval_shape(lambda: lm.init_params(cfg, jax.random.PRNGKey(0)))
+    o = jax.eval_shape(adamw.init, p)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+    tok = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one)
+    ATT.sdpa_paths.clear()
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(p), on_chip(o), {"tokens": tok, "labels": tok}).compile()
+    paths = [(path, reason) for path, _, reason in ATT.sdpa_paths]
+    return (compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes,
+            paths)
+
+
+@pytest.fixture(scope="module")
+def flash_step(topo):
+    return _train_step(topo)
+
+
+def _custom_calls(hlo_text: str):
+    """{instruction: op_name} of every tpu_custom_call.  An instruction's
+    attributes may run over several lines (a kernel's JSON metadata)."""
+    out = {}
+    for chunk in re.split(r"\n(?=\s*(?:ROOT\s+)?%)", hlo_text):
+        if 'custom_call_target="tpu_custom_call"' in chunk:
+            name = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)", chunk).group(1)
+            op = re.search(r'op_name="([^"]*)"', chunk)
+            out[name] = op.group(1) if op else ""
+    return out
+
+
+def test_train_step_records_flash(flash_step):
+    _, _, paths = flash_step
+    assert paths and set(paths) == {("flash", None)}
+
+
+def test_train_step_holds_flash_kernels(flash_step):
+    """Forward (and its remat recompute), dQ and dK/dV."""
+    kinds = {re.sub(r"\.\d+$", "", n) for n in _custom_calls(flash_step[0])}
+    assert kinds == {"splash_mha_fwd_residuals", "splash_mha_dq_no_residuals",
+                     "splash_mha_dkv_no_residuals"}, kinds
+
+
+def test_flash_kernels_carry_the_sdpa_scope(flash_step):
+    calls = _custom_calls(flash_step[0])
+    assert calls
+    for name, op_name in calls.items():
+        assert "attention/" in op_name and "/sdpa/" in op_name, (name, op_name)
+    assert any("transpose(" in op for op in calls.values())     # backward
+
+
+def test_flash_step_needs_less_memory_than_sdpa(topo, monkeypatch,
+                                                flash_step):
+    """Against the same step with the kernel refused: the parent's _sdpa."""
+    monkeypatch.setattr(FA, "refusal", lambda seq, head_dim: "refused")
+    text, temp, paths = _train_step(topo)
+    assert set(paths) == {("jnp", "refused")}
+    assert "tpu_custom_call" not in text
+    assert flash_step[1] < temp, (flash_step[1], temp)
