@@ -107,7 +107,7 @@ def phase_train(device) -> None:
     log(f"== train: {ARCH} full width, seq 4096, batch 2, 10 steps, one chip")
     ref = {}
 
-    def on_start(params, batch):
+    def on_start(params, opt_state, batch):
         ref["loss"] = f32_loss(params, batch)
         log(f"float32 reference step-0 loss {ref['loss']!r}")
 
@@ -180,7 +180,6 @@ def phase_serve(device) -> None:
 
 
 def phase_mesh(devices) -> None:
-    from repro.core import overlap as OV
     from repro.launch import train
 
     shape = ("--steps", "3", "--batch", "1", "--seq", "1024",
@@ -190,7 +189,6 @@ def phase_mesh(devices) -> None:
     ref_losses = [loss for _, loss in ref["history"]]
     log(f"one chip losses {ref_losses}")
     for mode in ("none", "fused"):
-        OV.fused_fallbacks.clear()
         rec = train.run(train_args(*shape, "--mesh-devices", "4",
                                    "--data", "1", "--mx", "2", "--my", "2",
                                    "--overlap", mode, "--comm-dtype", "bf16"),
@@ -203,7 +201,7 @@ def phase_mesh(devices) -> None:
             f"{n_k}")
         if mode == "fused":
             log(f"fused: collectives that failed their fused_ok gate and ran "
-                f"the ppermute ring: {OV.fused_fallbacks}")
+                f"the ppermute ring: {rec['fallbacks']}")
             if n_k == 0:
                 fail("overlap=fused compiled no ring kernel")
         errs = [abs(a - b) / abs(b) for a, b in zip(losses, ref_losses)]

@@ -158,6 +158,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced smoke config")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="train this many of the architecture's layers at "
+                         "its widths (default: the registry's depth), as "
+                         "one pipeline stage of a deeper deployment holds")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
@@ -242,11 +246,13 @@ def run(args, *, devices=None, on_start=None) -> dict:
     """Train as the command line ``args`` say; returns the run's record.
 
     ``devices`` (default ``jax.devices()``) are the chips the mesh is built
-    from.  ``on_start(params, batch)`` is called once before the first step
-    with the initial fp32 master params and the first batch (a reference
-    check's hook: the step donates both).  The record holds ``history``
-    (per-step ``(step, loss)``), ``step_s`` (per-step seconds, compile
-    excluded), ``compile_s`` and the ``compiled`` step."""
+    from; without a mesh the state lives on the first of them.
+    ``on_start(params, opt_state, batch)`` is called once before the first
+    step with the initial fp32 master params, the AdamW state and the first
+    batch (a reference check's hook: the step donates them).  The record
+    holds ``history`` (per-step ``(step, loss)``), ``step_s`` (per-step
+    seconds, compile excluded), ``compile_s``, the ``compiled`` step and
+    ``fallbacks``, the ``core.overlap.fused_fallbacks`` of its trace."""
     import time
     from collections import Counter
 
@@ -256,6 +262,7 @@ def run(args, *, devices=None, on_start=None) -> dict:
     from repro.checkpoint.manager import make_manager
     from repro.config import (CheckpointConfig, ParallelConfig, RunConfig,
                               get_config, get_smoke_config)
+    from repro.core import overlap as OV
     from repro.data.synthetic import Prefetcher, SyntheticLM
     from repro.launch.mesh import make_small_mesh
     from repro.optim import adamw
@@ -266,6 +273,8 @@ def run(args, *, devices=None, on_start=None) -> dict:
     from repro.models import attention as ATT, lm
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.num_layers:
+        cfg = cfg.scaled(num_layers=args.num_layers)
     rc = RunConfig("custom", "train", args.seq, args.batch, lr=args.lr)
     mesh = None
     pcfg = ParallelConfig(strategy=args.strategy, data=args.data,
@@ -282,26 +291,36 @@ def run(args, *, devices=None, on_start=None) -> dict:
         _train_pipeline(cfg, pcfg, rc, mesh, args)
         return {}
 
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    opt_state = adamw.init(params)
     extras = {}
     if cfg.family == "vlm":
         extras["patches"] = (cfg.frontend_stub_len, cfg.d_model)
     if cfg.family == "audio":
         extras["frames"] = (cfg.frontend_stub_len, cfg.d_model)
     ds = SyntheticLM(cfg.vocab_size, args.seq, args.batch, extras=extras)
+    # params and AdamW state are made where they live, each device making
+    # only its own shard: on a mesh no device ever holds the whole model
+    abstract = jax.eval_shape(lambda k: lm.init_params(cfg, k),
+                              jax.random.PRNGKey(0))
     bshard = None
-    if mesh is not None:
-        pspecs = SP.param_specs(params, mesh, pcfg)
+    if mesh is None:
+        pshard = oshard = jax.sharding.SingleDeviceSharding(
+            (devices or jax.devices())[0])
+    else:
+        pspecs = SP.param_specs(abstract, mesh, pcfg)
         pshard = SP.sharding_tree(pspecs, mesh)
-        params = jax.device_put(params, pshard)
-        ospecs = SP.opt_state_specs(pspecs, params, mesh, pcfg)
-        oshard = SP.sharding_tree(ospecs, mesh)
-        opt_state = jax.device_put(opt_state, oshard)
+        oshard = SP.sharding_tree(
+            SP.opt_state_specs(pspecs, abstract, mesh, pcfg), mesh)
         bshard = SP.sharding_tree(
             SP.batch_specs(mesh, pcfg, microbatched=False,
                            keys=tuple(ds.batch_at(0)), seq_len=args.seq),
             mesh)
+
+    def init(key):
+        p = lm.init_params(cfg, key)
+        return p, adamw.init(p)
+
+    params, opt_state = jax.jit(init, out_shardings=(pshard, oshard))(
+        jax.random.PRNGKey(0))
 
     gcfg = _guard_cfg(args)
     # bf16 compute / fp32 master (ModelConfig.dtype_note) on any device count
@@ -334,9 +353,10 @@ def run(args, *, devices=None, on_start=None) -> dict:
                                              start, ds.batch_at)
     batch0 = ds.batch_at(dix(start))
     if on_start is not None:
-        on_start(params, batch0)
+        on_start(params, opt_state, batch0)
     t0 = time.perf_counter()
     ATT.sdpa_paths.clear()
+    OV.fused_fallbacks.clear()
     compiled = ts.lower(params, opt_state, batch0).compile()
     compile_s = time.perf_counter() - t0
     print(f"compiled train step in {compile_s:.1f}s: "
@@ -345,6 +365,9 @@ def run(args, *, devices=None, on_start=None) -> dict:
     print("attention cores traced: " + ", ".join(
         f"{n} {path}" + (f" ({reason})" if reason else "")
         for (path, reason), n in sorted(paths.items(), key=str)))
+    fallbacks = list(OV.fused_fallbacks)
+    print(f"fused collectives that took the ppermute ring: {len(fallbacks)}"
+          + "".join(f"; {op} x{x} w{w}" for op, x, w in fallbacks))
     it = Prefetcher(stream, sharding=bshard)
     state = {"params": params, "opt_state": opt_state}
     timer = StepTimer()
@@ -362,7 +385,7 @@ def run(args, *, devices=None, on_start=None) -> dict:
     h = state["history"]
     print(f"final loss {h[-1][1]:.4f} (first {h[0][1]:.4f})")
     return {"history": h, "step_s": state["step_s"], "compile_s": compile_s,
-            "compiled": compiled}
+            "compiled": compiled, "fallbacks": fallbacks}
 
 
 def main(argv=None):

@@ -3,6 +3,8 @@ device grid, on the CPU at smoke widths."""
 
 import math
 import os
+import subprocess
+import sys
 from types import SimpleNamespace
 
 import jax
@@ -13,18 +15,66 @@ from repro import compat
 from repro.launch import mesh as M
 from repro.launch import serve, train
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def test_train_run_smoke():
     seen = []
     args = train.build_parser().parse_args(
         ["--smoke", "--steps", "3", "--batch", "2", "--seq", "32",
          "--remat", "full"])
-    rec = train.run(args, on_start=lambda p, b: seen.append(b["tokens"].shape))
+    rec = train.run(args, on_start=lambda p, o, b: seen.append(
+        b["tokens"].shape))
     assert seen == [(2, 32)]
     assert [s for s, _ in rec["history"]] == [0, 1, 2]
     assert all(math.isfinite(loss) for _, loss in rec["history"])
     assert len(rec["step_s"]) == 3 and rec["compile_s"] > 0
     assert "tpu_custom_call" not in rec["compiled"].as_text()
+
+
+def test_train_run_one_device_init_is_init_params():
+    """The jitted init makes, on one device, the very params of
+    ``lm.init_params`` and a zero AdamW state."""
+    from repro.config import get_smoke_config
+    from repro.models import lm
+
+    seen = {}
+
+    def on_start(params, opt_state, batch):
+        seen["params"] = jax.tree.map(np.asarray, params)
+        seen["moments"] = jax.tree.map(np.asarray, (opt_state.mu,
+                                                    opt_state.nu))
+        seen["devices"] = {a.sharding for a in jax.tree.leaves(params)}
+
+    args = train.build_parser().parse_args(
+        ["--smoke", "--arch", "granite-34b", "--num-layers", "3", "--steps",
+         "1", "--batch", "2", "--seq", "32"])
+    train.run(args, on_start=on_start)
+    cfg = get_smoke_config("granite-34b").scaled(num_layers=3)
+    want = lm.init_params(cfg, jax.random.PRNGKey(0))
+    assert jax.tree.structure(want) == jax.tree.structure(seen["params"])
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(seen["params"])):
+        assert a.dtype == b.dtype and np.array_equal(np.asarray(a), b)
+    assert want["blocks"]["mlp"]["w1"].shape[0] == 3
+    assert all(not m.any() for m in jax.tree.leaves(seen["moments"]))
+    assert seen["devices"] == {
+        jax.sharding.SingleDeviceSharding(jax.devices()[0])}
+
+
+def test_train_run_makes_sharded_state_on_a_2x2_grid():
+    """On 4 virtual CPU devices, ``overlap`` none and fused: every leaf of
+    the params and AdamW state has the sharding of ``param_specs`` /
+    ``opt_state_specs``, and the step-0 loss matches one device's
+    (``tests/_mp/check_launch_sharded.py``, which states the tolerance)."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tests", "_mp",
+                                      "check_launch_sharded.py")],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    assert "LAUNCH SHARDED STATE OK" in r.stdout
 
 
 def test_serve_run_smoke():
