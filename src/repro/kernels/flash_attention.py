@@ -11,8 +11,9 @@ Operands enter the MXU in their own dtype (bf16 on the model path) and
 accumulate in float32; the running max, sum and log-sum-exp are float32.
 
 ``models/attention.py`` puts :func:`flash_attention` on the causal
-self-attention of a single-device training step; :func:`refusal` is the
-kernel's part of that dispatch.
+self-attention of a training step, on one device or, on a mesh, on each
+chip's whole heads inside a ``shard_map``; :func:`refusal` is the kernel's
+part of that dispatch.
 """
 
 from __future__ import annotations

@@ -2,10 +2,11 @@
 cross-attention (whisper).  All projections route through PCtx so the Hecaton
 §IV-C dataflow (sequence gathered, heads sharded, AG/RS only) applies uniformly.
 
-The attention core of a causal self-attention over whole sequences on one
-device (a training step) is the flash kernel of kernels/flash_attention.py
-where the step is lowered for TPU.  Every other core (KV caches, cross- and
-non-causal attention, MLA, a mesh, other platforms) is ``_sdpa``, a softmax
+The attention core of a causal self-attention over whole sequences (a
+training step) is the flash kernel of kernels/flash_attention.py where the
+step is lowered for TPU; on a mesh it runs in a shard_map on each chip's
+whole heads.  Every other core (KV caches, cross- and non-causal attention,
+MLA, K/V heads a mesh cannot split, other platforms) is ``_sdpa``, a softmax
 chunked over q blocks (``lax.map``) so that no [Sq,Sk] score matrix is built
 whole, or ``_sdpa_grouped_decode``.  ``sdpa_paths`` records which was taken.
 """
@@ -17,7 +18,9 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
+from repro import compat
 from repro.config import ModelConfig
 from repro.core import quant as QU
 from repro.kernels import flash_attention as FA
@@ -270,34 +273,73 @@ def quant_paged_gather(arena, scales, block_table, dtype):
 sdpa_paths: list = []
 
 
-def _flash_ok(pctx, q, k, v, *, causal: bool, cache) -> bool:
+def _flash_ok(pctx, q, k, v, *, causal: bool, cache, layout=None) -> bool:
     """Whether the flash kernel takes this core (q [B,Sq,nh,dh], k [B,Sk,..],
     v [B,Sk,..,dv]), recorded in ``sdpa_paths``.  It takes a causal
-    self-attention over whole sequences on one device: no KV cache (decode,
-    prefill-continue), no mesh (its head axes would need a shard_map), v as
-    wide as q and k, and the kernel's tiling."""
+    self-attention over whole sequences: no KV cache (decode,
+    prefill-continue), v as wide as q and k, the kernel's tiling, and on a
+    mesh a layout whose shards :func:`_on_heads` can cut."""
     reason = ("kv cache" if cache is not None else
               "not causal" if not causal else
               "Sq != Sk" if q.shape[1] != k.shape[1] else
-              "mesh" if pctx.mesh is not None else
               "v head dim != qk head dim" if v.shape[-1] != q.shape[-1] else
-              FA.refusal(q.shape[1], q.shape[-1]))
+              FA.refusal(q.shape[1], q.shape[-1]) or
+              _mesh_refusal(pctx, layout, q.shape[0], k.shape[2]))
     sdpa_paths.append(("jnp" if reason else "flash", tuple(q.shape), reason))
     return reason is None
+
+
+def _kv_spec(pctx, layout, nkv: int):
+    """K/V's spec beside ``layout.q_spec()``, K/V not repeated: on q's head
+    axes where the kv heads divide over them, replicated over the model axes
+    for one (MQA) kv head, else None."""
+    qs = layout.q_spec()
+    if nkv % pctx.ax.size(layout.head_axes) == 0:
+        return qs
+    return P(qs[0], None, None, None) if nkv == 1 else None
+
+
+def _mesh_refusal(pctx, layout, batch: int, nkv: int):
+    """Why a mesh's layout cannot give each chip whole heads of whole
+    sequences of its own batch rows, or None (also without a mesh)."""
+    if pctx.mesh is None:
+        return None
+    if layout is None:
+        return "mesh without an attention layout"
+    n_b = pctx.ax.size(layout.batch_axes)
+    if batch % n_b:
+        return f"batch {batch} not divisible over {n_b} batch shards"
+    if _kv_spec(pctx, layout, nkv) is None:
+        return (f"kv heads {nkv} not divisible over "
+                f"{pctx.ax.size(layout.head_axes)} head shards")
+    return None
+
+
+def _on_heads(pctx, layout, core, q, k, v):
+    """``core(q, k, v)`` on each chip's batch rows and whole heads: a
+    shard_map over ``layout`` on a mesh (K/V by :func:`_kv_spec`; its
+    transpose sums dK/dV over the chips that share a kv head), the call
+    itself without one.  The sequence and the head width stay whole."""
+    if pctx.mesh is None:
+        return core(q, k, v)
+    qs, kvs = layout.q_spec(), _kv_spec(pctx, layout, k.shape[2])
+    return compat.shard_map(core, pctx.mesh, (qs, kvs, kvs), qs)(q, k, v)
+
+
+def _flash(q, k, v, *, interpret: bool = False):
+    """The kernel on q [B,S,nh,dh], k,v [B,S,nkv,dh]."""
+    t = lambda x: x.transpose(0, 2, 1, 3)       # noqa: E731
+    return t(FA.flash_attention(t(q), t(k), t(v), interpret=interpret))
 
 
 def _causal_self_attention(q, k, v, q_block: int):
     """q [B,S,nh,dh]; k,v [B,S,nkv,dh] (GQA, not repeated).  The flash kernel
     where the step is lowered for TPU, ``_sdpa`` on any other platform."""
-    def flash(q, k, v):
-        t = lambda x: x.transpose(0, 2, 1, 3)       # noqa: E731
-        return t(FA.flash_attention(t(q), t(k), t(v)))
-
     def chunked(q, k, v):
         g = q.shape[2] // k.shape[2]
         return _sdpa(q, _repeat_kv(k, g), _repeat_kv(v, g), causal=True,
                      q_offset=0, q_block=q_block)
-    return lax.platform_dependent(q, k, v, tpu=flash, default=chunked)
+    return lax.platform_dependent(q, k, v, tpu=_flash, default=chunked)
 
 
 @jax.named_scope("sdpa")
@@ -436,9 +478,10 @@ def apply_attn(pctx, cfg: ModelConfig, p, x, *, positions, causal: bool = True,
         k, v = kc, vc
         kv_len, q_off = new_cache.length, cache.length
 
-    if _flash_ok(pctx, q, k, v, causal=causal, cache=cache):
-        o = _causal_self_attention(q, k.astype(q.dtype), v.astype(q.dtype),
-                                   q_block)
+    if _flash_ok(pctx, q, k, v, causal=causal, cache=cache, layout=layout):
+        o = _on_heads(pctx, layout,
+                      lambda q, k, v: _causal_self_attention(q, k, v, q_block),
+                      q, k.astype(q.dtype), v.astype(q.dtype))
     elif cache is not None and S == 1:
         # decode: grouped GQA, KV cache stays kv-head-sharded
         kv_lay = pctx.attn_layout(nkv, B)

@@ -1,10 +1,12 @@
 """Which attention core each call takes, from the trace-time record
 ``models.attention.sdpa_paths``: the flash kernel on a causal self-attention
-over whole sequences on one device (a training step), the jnp ``_sdpa`` on
-every other call.  Tracing only (``jax.eval_shape``): the record is made at
-trace time, and the platform is picked when the step is lowered
-(``tests/test_tpu_compile.py`` lowers one for a v5e)."""
+over whole sequences (a training step; on a mesh, where each chip can hold
+whole heads), the jnp ``_sdpa`` on every other call.  Tracing only
+(``jax.eval_shape``): the record is made at trace time, and the platform is
+picked when the step is lowered (``tests/test_tpu_compile.py`` lowers one
+for a v5e)."""
 
+import json
 import os
 import subprocess
 import sys
@@ -137,9 +139,10 @@ def test_cpu_lowering_runs_sdpa():
                                   np.asarray(want, np.float32))
 
 
-def test_mesh_step_records_jnp():
-    """A train step on a 2x2 Hecaton mesh of four virtual CPU devices keeps
-    _sdpa (``tests/_mp/check_sdpa_paths.py``)."""
+@pytest.fixture(scope="module")
+def mesh_run():
+    """``tests/_mp/check_sdpa_paths.py`` on a 2x2 Hecaton mesh of four
+    virtual CPU devices, run once for the tests below."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
@@ -148,4 +151,27 @@ def test_mesh_step_records_jnp():
                                       "check_sdpa_paths.py")],
         capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, f"\n{r.stdout}\n{r.stderr[-3000:]}"
-    assert "MESH STEP KEEPS SDPA" in r.stdout
+    line = next(ln for ln in r.stdout.splitlines() if ln.startswith("SDPA "))
+    return json.loads(line[len("SDPA "):])
+
+
+@pytest.mark.parametrize("case,want", [
+    ("mqa", ["flash", None]),
+    ("gqa", ["flash", None]),
+    ("kv2", ["jnp", "kv heads 2 not divisible over 4 head shards"]),
+])
+def test_mesh_step_path(mesh_run, case, want):
+    """A train step on the 2x2 mesh: the kernel takes every core whose K/V
+    heads divide over the 4 head shards or are one (MQA), replicated; two
+    kv heads keep _sdpa."""
+    assert mesh_run["paths"][case] == [want]
+
+
+@pytest.mark.parametrize("case", ["mqa", "gqa"])
+def test_mesh_flash_core_matches_sdpa(mesh_run, case):
+    """The shard_mapped kernel (interpret mode) against the unsharded _sdpa
+    with K/V repeated, at bf16 tolerances: forward and q/k/v gradients (the
+    transpose sums dK/dV over the head shards)."""
+    got = mesh_run["numerics"][case]
+    assert got["fwd_abs"] <= 2e-2, got
+    assert max(got["grad_rel"].values()) <= 1e-2, got

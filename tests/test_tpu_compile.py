@@ -3,11 +3,11 @@ widths.
 
 Each test compiles for a described (not attached) v5e 2x2 host: the fused
 ring collectives of kernels/ring_matmul.py on the Hecaton mx=my=2 grid,
-forward and ``jax.grad``, bf16 and int8 wire, the plain tile matmul, and a
-one-chip train step with the flash attention kernel of
-kernels/flash_attention.py, then checks that the compiled program holds the
-Pallas kernel (``tpu_custom_call``).  Nothing runs, so this says nothing about results or
-speed; it catches what interpret mode cannot (tile alignment, VMEM, ref
+forward and ``jax.grad``, bf16 and int8 wire, the plain tile matmul, and
+train steps with the flash attention kernel of kernels/flash_attention.py
+(one chip, and a granite-shaped step on the 2x2 grid), then checks that the
+compiled program holds the Pallas kernel (``tpu_custom_call``).  Nothing
+runs, so this says nothing about results or speed; it catches what interpret mode cannot (tile alignment, VMEM, ref
 shapes).  The topology is described only inside the module fixture, after a
 test has started: only one process may load the TPU library.
 """
@@ -207,3 +207,110 @@ def test_flash_step_needs_less_memory_than_sdpa(topo, monkeypatch,
     assert set(paths) == {("jnp", "refused")}
     assert "tpu_custom_call" not in text
     assert flash_step[1] < temp, (flash_step[1], temp)
+
+
+# ---------------------------------------------------------------------------
+# flash attention on the Hecaton 2x2 train step
+# ---------------------------------------------------------------------------
+
+def _mesh_train_step(mesh):
+    """A granite-shaped step cut to small widths (MQA: 4 query heads and one
+    128-wide K/V head, 2 layers, seq 4096 x batch 2, full remat) on the
+    described 2x2 grid: (compiled text, temporaries, traced paths)."""
+    from repro.config import ModelConfig, ParallelConfig, RunConfig
+    from repro.models import attention as ATT
+    from repro.models import lm
+    from repro.parallel import specs as SP
+    from repro.optim import adamw
+    from repro.train import step as TS
+
+    cfg = ModelConfig(name="granite-shaped", family="dense", num_layers=2,
+                      d_model=512, num_heads=4, num_kv_heads=1, head_dim=128,
+                      d_ff=2048, vocab_size=1024, mlp_kind="gelu",
+                      tie_embeddings=False)
+    pcfg = ParallelConfig(strategy="hecaton", data=1, model=4, mx=2, my=2,
+                          microbatches=1, remat="full")
+    p = jax.eval_shape(lambda: lm.init_params(cfg, jax.random.PRNGKey(0)))
+    pspecs = SP.param_specs(p, mesh, pcfg)
+    o = jax.eval_shape(adamw.init, p)
+    ospecs = SP.opt_state_specs(pspecs, p, mesh, pcfg)
+
+    def sds(tree, specs):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree,
+            SP.sharding_tree(specs, mesh))
+    tok = jax.ShapeDtypeStruct((2, 4096), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data", "mx")))
+    step = TS.build_train_step(cfg, pcfg, RunConfig("t", "train", 4096, 2),
+                               mesh)
+    ATT.sdpa_paths.clear()
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        sds(p, pspecs), sds(o, ospecs),
+        {"tokens": tok, "labels": tok}).compile()
+    paths = [(path, reason) for path, _, reason in ATT.sdpa_paths]
+    return (compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes,
+            paths)
+
+
+@pytest.fixture(scope="module")
+def mesh_flash_step(mesh):
+    return _mesh_train_step(mesh)
+
+
+def test_mesh_step_records_flash(mesh_flash_step):
+    _, _, paths = mesh_flash_step
+    assert paths and set(paths) == {("flash", None)}
+
+
+def test_mesh_step_holds_flash_kernels(mesh_flash_step):
+    """Inside the shard_map over the head axes: forward (and its remat
+    recompute), dQ and dK/dV."""
+    kinds = {re.sub(r"\.\d+$", "", n)
+             for n in _custom_calls(mesh_flash_step[0])}
+    assert kinds == {"splash_mha_fwd_residuals", "splash_mha_dq_no_residuals",
+                     "splash_mha_dkv_no_residuals"}, kinds
+
+
+def test_mesh_flash_step_needs_no_more_memory_than_sdpa(mesh, monkeypatch,
+                                                         mesh_flash_step):
+    """Against the same step with the kernel refused: the parent's _sdpa
+    with the one K/V head repeated."""
+    monkeypatch.setattr(FA, "refusal", lambda seq, head_dim: "refused")
+    text, temp, paths = _mesh_train_step(mesh)
+    assert set(paths) == {("jnp", "refused")}
+    assert "tpu_custom_call" not in text
+    assert mesh_flash_step[1] <= temp, (mesh_flash_step[1], temp)
+
+
+def _site_a():
+    def f(q):
+        return FA.flash_attention(q, q, q)
+    return f
+
+
+def _site_b():
+    def f(q):
+        return FA.flash_attention(q, q, q)
+    return f
+
+
+def test_step_hlo_kernel_digest_ignores_call_site(topo):
+    """``tools/step_hlo.py`` compares steps across checkouts and callers:
+    the same kernel called from two places prints the same text, though
+    each serialized body carries its call site's file, line and function."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "step_hlo", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "step_hlo.py"))
+    step_hlo = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(step_hlo)
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 1, 256, 128), jnp.bfloat16, sharding=one)
+    raw = []
+    for site in (_site_a, _site_b):
+        jax.clear_caches()          # else the kernel's first trace is reused
+        raw.append(jax.jit(site()).lower(q).compile().as_text())
+    bodies = [re.findall(r'"body":"([^"]*)"', t) for t in raw]
+    assert bodies[0] and bodies[0] != bodies[1]
+    a, b = (step_hlo.strip_metadata(t) for t in raw)
+    assert a == b and '"body":"sha256:' in a

@@ -10,11 +10,15 @@ was.  ``--step decode`` prints instead the serve decode step of the cell's
 model (one token for each of the cell's batch rows, against a dense KV cache
 of the cell's sequence length): a change to the training path only leaves
 it as it was.  Taken out: the debug tables between the ``HloModule`` line
-and the first computation (source files, functions, stack frames) and every
-instruction's ``metadata={...}``.
+and the first computation (source files, functions, stack frames), every
+instruction's ``metadata={...}``, and the source locations that a Pallas
+kernel's serialized body carries (each body is printed without them and
+replaced by that text's sha256).
 """
 
 import argparse
+import base64
+import hashlib
 import os
 import re
 import sys
@@ -26,7 +30,23 @@ def strip_metadata(hlo_text: str) -> str:
     first = next(i for i, line in enumerate(lines)
                  if line.startswith(("%", "ENTRY")))
     body = "\n".join(lines[:1] + lines[first:])
-    return re.sub(r",? metadata=\{[^}]*\}", "", body) + "\n"
+    body = re.sub(r",? metadata=\{[^}]*\}", "", body)
+    return re.sub(r'"body":"([^"]*)"', _kernel_digest, body) + "\n"
+
+
+def _kernel_digest(m) -> str:
+    """A Mosaic kernel body (MLIR bytecode, base64) as the sha256 of its
+    text without debug locations: the call site's file, line and function
+    change with the checkout and the caller, the kernel does not."""
+    from jax._src.lib import tpu
+    from jaxlib.mlir import ir
+
+    with ir.Context() as ctx:
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        asm = ir.Module.parse(base64.b64decode(m.group(1))).operation.get_asm(
+            enable_debug_info=False)
+    return f'"body":"sha256:{hashlib.sha256(asm.encode()).hexdigest()}"'
 
 
 def main(argv=None) -> int:
