@@ -1,8 +1,8 @@
 """The paper's own evaluation ladder (§VI-A): Llama family with doubling hidden
-size, used by benchmarks/scaling.py to reproduce Fig. 9 (weak scaling).
+size; tools/paper_tables.py prints the paper's analytic tables on this ladder.
 
 These are registered with a `paper-` prefix; they are NOT part of the 40
-assigned cells but drive the paper-faithfulness benchmarks.
+assigned cells.
 """
 from repro.config import ModelConfig, register
 

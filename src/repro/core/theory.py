@@ -208,8 +208,7 @@ def pipeline_bubble_fraction(p: int, m: int) -> float:
     each, so the makespan is ``2(m+p-1)`` ticks of which every stage idles
     ``2(p-1)`` — the classic PipeDream-flush / Megatron-LM bubble.  The
     simulated schedule (parallel/pipeline.schedule_1f1b) reproduces this
-    exactly; benchmarks/comm_model.pipeline_rows asserts the match in the
-    emitted ``theory_pipeline_*`` rows.
+    exactly; tests/test_pipeline.py asserts the match.
     """
     if p <= 1:
         return 0.0

@@ -18,7 +18,7 @@ Two layers live here:
    ticks ``2*(p-1)`` per stage, peak in-flight ``min(p-s, m)``) are unit
    tested without devices, and ``core/theory.py``'s bubble-fraction
    prediction ``(p-1)/(m+p-1)`` is checked against the simulated table
-   (``theory_pipeline_*`` rows in benchmarks/comm_model.py).
+   (tests/test_pipeline.py).
 
 2. **The runner** (:class:`PipelineRunner`) — executes the table on a
    multi-pod mesh.  Each stage runs on its pod's sub-mesh

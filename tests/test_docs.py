@@ -34,7 +34,7 @@ def test_design_has_cited_sections():
 def test_readme_covers_required_topics():
     with open(os.path.join(ROOT, "README.md")) as f:
         text = f.read()
-    for required in ("Quickstart", "Repo map", "BENCH_overlap.json",
+    for required in ("Quickstart", "Repo map", "BENCHMARK.json",
                      "pytest", "examples/quickstart.py",
                      "`none`", "`ring`", "`bidir`", "`fused`"):
         assert required in text, f"README.md missing {required!r}"

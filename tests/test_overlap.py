@@ -1,9 +1,9 @@
 """Ring-overlapped collective matmuls (core/overlap.py).
 
 Numerics run in a subprocess on a fake 8-device topology (tests/_mp style);
-the HLO assertion uses the extended benchmarks/hlo_compare.py counter to prove
-that overlap="ring"/"bidir" replaces every bulk all-gather/reduce-scatter in
-the FFN hot path (forward AND backward) with collective-permute chains.
+the HLO assertions use the tests/_mp/hlo_bytes.py counters to prove that
+overlap="ring"/"bidir" replaces every bulk all-gather/reduce-scatter in the
+FFN hot path (forward AND backward) with collective-permute chains.
 In-process tests cover the pure dispatch/fallback logic and config plumbing.
 """
 
@@ -13,8 +13,9 @@ import sys
 
 import pytest
 
+from _mp import hlo_bytes
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)            # for `benchmarks` imports
 
 
 def _run(script, *args):
@@ -47,9 +48,7 @@ def test_overlap_hlo_collective_permute_replaces_bulk():
     fwd AND bwd, MoE EP/TP gathers+scatters, megatron column/row FFN) have a
     collective-permute chain and ZERO bulk all-gather/reduce-scatter — while
     the bulk mode has the inverse on the FFN path."""
-    from benchmarks import hlo_compare
-    out = hlo_compare.run_overlap()
-    assert "error" not in out, out.get("error")
+    out = hlo_bytes.run("overlap")
     for tag in ("fwd", "fwd_bwd"):
         none_b = out["none"][tag]["bytes"]
         assert none_b.get("all-gather", 0) > 0
@@ -80,13 +79,12 @@ def test_seq_residual_hlo_no_block_boundary_gather():
     train step (fwd+bwd) has ZERO bulk collectives — no reduce-scatter and
     ZERO all-gather bytes: since fused_lm_loss_seq rings the head's vocab
     chunks with the labels kept sharded, even the old sub-KB int32 label
-    gather is gone — while the replicated layout keeps residual-sized bulk
-    gathers in EVERY mode.  Per-die residual-stream bytes shrink by exactly
-    1/n_model, and the seq layout never moves more bulk bytes (AG+RS+AR)
-    than the replicated one."""
-    from benchmarks import hlo_compare
-    out = hlo_compare.run_residual()
-    assert "error" not in out, out.get("error")
+    gather is gone — while the replicated layout pays a residual-sized bulk
+    collective (AG+RS+AR; XLA may emit it as an all-reduce rather than an
+    all-gather) in EVERY ring mode.  Per-die residual-stream bytes shrink by
+    exactly 1/n_model, and the seq layout never moves more bulk bytes
+    (AG+RS+AR) than the replicated one."""
+    out = hlo_bytes.run("residual")
     n = out["n_model"]
 
     def bulk(row):
@@ -101,9 +99,9 @@ def test_seq_residual_hlo_no_block_boundary_gather():
         # fused seq loss, so NO all-gather of any size survives
         assert b.get("all-gather", 0) == 0, (mode, b)
         assert b.get("collective-permute", 0) > 0, (mode, b)
-        # the replicated layout pays residual-sized bulk gathers in all modes
-        rb = out["replicated"][mode]["bytes"]
-        assert rb.get("all-gather", 0) > 1e5, (mode, rb)
+        # the replicated layout pays a residual-sized bulk collective
+        rb = out["replicated"][mode]
+        assert bulk(rb) > 1e5, (mode, rb["bytes"])
     for mode in ("none", "ring", "bidir", "fused"):
         assert bulk(out["seq"][mode]) <= bulk(out["replicated"][mode]), mode
         # per-die activation bytes for the layer scan shrink by 1/n_model
@@ -281,32 +279,6 @@ def test_mixer_in_many_matches_per_weight():
                                    np.asarray(pctx.mixer_in(x, w)), rtol=1e-6)
 
 
-def test_fit_overlap_eff():
-    from benchmarks.comm_model import OVERLAP_EFF, fit_overlap_eff
-
-    # synthetic: compute 70us, comm 30us, ring hides 2/3, fused hides all
-    times = {"none": {"ffn_us": 100.0, "linear_us": 200.0},
-             "ring": {"ffn_us": 80.0, "linear_us": 160.0},
-             "fused": {"ffn_us": 70.0, "linear_us": 140.0}}
-    fit = fit_overlap_eff(times)
-    assert fit is not None
-    assert fit["eff"]["none"] == 0.0
-    # exact recovery requires the true rho=0.3 to be on the search grid;
-    # the prior pulls toward it since eff_fused(0.3)=1.0 ≈ prior 0.95
-    assert 0.5 < fit["eff"]["ring"] < 0.9
-    assert fit["eff"]["fused"] > 0.85
-    assert fit["eff"]["ring"] < fit["eff"]["fused"]
-    assert 0.0 < fit["comm_fraction"] < 1.0
-    # CPU-style regression (ring modes slower than bulk) clips to 0
-    slow = {"none": {"ffn_us": 100.0}, "ring": {"ffn_us": 150.0}}
-    fit2 = fit_overlap_eff(slow)
-    assert fit2["eff"]["ring"] == 0.0 and "ring" in fit2["clipped"]
-    # garbage in → None, not a crash
-    assert fit_overlap_eff(None) is None
-    assert fit_overlap_eff({"ring": {"ffn_us": 1.0}}) is None
-    assert set(OVERLAP_EFF) == {"none", "ring", "bidir", "fused"}
-
-
 def test_mesh_none_paths_ignore_overlap():
     import jax
     import jax.numpy as jnp
@@ -344,9 +316,7 @@ def test_quant_hlo_byte_cut():
     the bf16 wire on the 2-layer megatron LM train step (fwd+bwd), on every
     overlap mode — and the bulk AG/RS total stays zero for BOTH wire dtypes
     (the wire dtype must never re-bulk a ring)."""
-    from benchmarks import hlo_compare
-    out = hlo_compare.run_quant()
-    assert "error" not in out, out.get("error")
+    out = hlo_bytes.run("quant")
     for mode in ("ring", "bidir", "fused"):
         row = out[mode]
         cp = {cd: row[cd]["bytes"].get("collective-permute", 0.0)
@@ -411,12 +381,15 @@ def test_quant_single_device_smoke():
 
 
 def test_comm_model_wire_dtype_rows():
-    """Regression (satellite bugfix): the theory rows' bytes-per-element now
-    flows from the comm dtype — comm_bytes_per_elt is the single source, the
-    SRAM minimal-unit check uses the ladder's element width instead of the
-    hardcoded fp32 (=4), and the int8 wire shows up as a NoP-only cut."""
-    from benchmarks.comm_model import (comm_bytes_per_elt, fit_overlap_eff,
-                                       overlap_rows, run)
+    """The paper tables' bytes-per-element flows from the comm dtype
+    (comm_bytes_per_elt is the single source), and the SRAM minimal-unit
+    check uses the ladder's element width instead of a hardcoded fp32."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "paper_tables", os.path.join(ROOT, "tools", "paper_tables.py"))
+    paper_tables = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(paper_tables)
+    comm_bytes_per_elt = paper_tables.comm_bytes_per_elt
 
     assert comm_bytes_per_elt("bf16", 4096) == 2.0
     assert comm_bytes_per_elt("int8", 4096) == pytest.approx(1 + 4 / 4096)
@@ -424,25 +397,10 @@ def test_comm_model_wire_dtype_rows():
     assert comm_bytes_per_elt("int8", 8) == 2.0
     with pytest.raises(ValueError):
         comm_bytes_per_elt("fp8", 4096)
-    big_b = {r["mode"]: r for r in overlap_rows()
-             if r["workload"] == "llama3.1-405b"}
-    big_i = {r["mode"]: r for r in overlap_rows(comm_dtype="int8")
-             if r["workload"] == "llama3.1-405b"}
-    # pinned: the corrected rows (bulk bf16 ≡ 1.0 by normalization; int8
-    # halves the exposed-NoP share of the bulk critical path)
-    assert big_b["none"]["latency_norm"] == pytest.approx(1.0)
-    assert big_i["none"]["latency_norm"] == pytest.approx(0.772, rel=0.02)
-    for m in ("none", "ring", "bidir", "fused"):
-        assert big_i[m]["latency"] <= big_b[m]["latency"], m
-        assert big_i[m]["wire_bytes_per_elt"] < big_b[m]["wire_bytes_per_elt"]
     # the SRAM check is consistent with the ladder's own element width: the
-    # paper's verdict rows (flat/torus overflow, optimus+hecaton fit) hold
-    verdict = {(r["package"], r["method"]): r["sram_ok"] for r in run()
+    # paper's verdict rows (flat ring overflows, optimus+hecaton fit) hold
+    verdict = {(r["package"], r["method"]): r["sram_ok"]
+               for r in paper_tables.fig8_rows()
                if r["workload"] == "llama3.1-405b"}
     assert verdict[("standard", "hecaton")] and verdict[("standard", "optimus")]
     assert not verdict[("standard", "flat_ring")]
-    # calibrated fit: attributing a byte cut to the wire lowers the comm term
-    # the efficiencies have to explain — the wire kwarg must change the fit
-    times = {"none": {"ffn_us": 100.0}, "ring": {"ffn_us": 80.0}}
-    assert (fit_overlap_eff(times, wire={"ring": 0.5})["eff"]["ring"]
-            != fit_overlap_eff(times)["eff"]["ring"])

@@ -3,8 +3,8 @@
 Numerics (fused kernels vs the core/overlap.py ring reference vs bulk
 collectives, fwd+grad, epilogues, gated pair, non-tile-aligned fallback) run
 in a subprocess on a fake 8-device topology (tests/_mp style).  In-process
-tests cover the block/gating logic, the degenerate single-device ring, the
-``"fused"`` mode plumbing, and the overlap-aware comm-model extension.
+tests cover the block/gating logic, the degenerate single-device ring and
+the ``"fused"`` mode plumbing.
 """
 
 import os
@@ -17,7 +17,6 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)            # for `benchmarks` imports
 
 
 def _run(script):
@@ -140,27 +139,3 @@ def test_remote_dma_shim():
     # ppermute-emulated interpret path
     assert compat.remote_dma_supported() is False
 
-
-# ---------------------------------------------------------------------------
-# In-process: overlap-aware comm model (Table III extension)
-# ---------------------------------------------------------------------------
-
-
-def test_overlap_comm_model_monotone():
-    from benchmarks.comm_model import (OVERLAP_EFF, effective_bandwidth,
-                                       exposed_comm, overlap_rows)
-
-    assert set(OVERLAP_EFF) == {"none", "ring", "bidir", "fused"}
-    comm, compute = 1.0, 10.0
-    exp = [exposed_comm(comm, compute, m)
-           for m in ("none", "ring", "bidir", "fused")]
-    assert exp[0] == comm                       # bulk: fully exposed
-    assert exp[0] > exp[1] > exp[2] > exp[3] > 0
-    # compute-bound hiding saturates: tiny compute exposes almost everything
-    assert exposed_comm(1.0, 0.01, "fused") == pytest.approx(0.99)
-    assert effective_bandwidth(64e9, comm, compute, "fused") > 64e9
-    assert effective_bandwidth(64e9, comm, compute, "none") == 64e9
-    rows = overlap_rows()
-    by_mode = {r["mode"]: r for r in rows if r["workload"] == "llama3.1-405b"}
-    assert by_mode["fused"]["latency"] <= by_mode["ring"]["latency"] \
-        <= by_mode["none"]["latency"]
